@@ -12,12 +12,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 import repro  # noqa: F401
 from repro.configs import get_reduced_config
 from repro.data.pipeline import DataConfig, SyntheticLMData
 from repro.distributed import compression
+from repro.distributed.shard_fused import shard_map
+from repro.launch.mesh import make_mesh
 from repro.models import Model
 from repro.optim import adamw
 
@@ -31,7 +32,7 @@ def main(argv=None):
     cfg = get_reduced_config("repro-100m", act_impl="jnp")
     model = Model(cfg)
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("dp",))
+    mesh = make_mesh((n_dev,), ("dp",))
     B = 2 * n_dev
 
     params = model.init(jax.random.PRNGKey(0))
@@ -44,7 +45,6 @@ def main(argv=None):
         mesh=mesh,
         in_specs=(P(), P(), P("dp")),
         out_specs=(P(), P(), P()),
-        check_rep=False,
     )
     def dp_step(state, residuals, batch):
         # local grads on this worker's shard

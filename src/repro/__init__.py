@@ -1,6 +1,3 @@
 """repro: Flex-SFU (non-uniform PWL activation approximation) on TPU/JAX."""
-from . import _jax_compat
-
-_jax_compat.install()
 
 __version__ = "0.1.0"

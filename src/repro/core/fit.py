@@ -58,8 +58,8 @@ def _loss_fn(spec: F.FunctionSpec, x, fx, w, p, v, m_l, m_r):
     """Trapezoid MSE on grid x with weights w (∑w = 1 after /(b-a)).
 
     PRECONDITION: p is sorted.  The trainer re-sorts (p, v, Adam state) after
-    every update *outside* the differentiated region — grad-through-sort is
-    unsupported by this environment's jaxlib (see repro/_jax_compat.py)."""
+    every update *outside* the differentiated region, so no gradient flows
+    through the sort."""
     vs = _effective_values(spec, p, v)
     y = pwl.eval_interp(x, p, vs, m_l, m_r)
     return jnp.sum(w * (y - fx) ** 2)

@@ -15,7 +15,7 @@ de-quantized values, matching the paper's SIMD integer comparator.
 
 Accumulator width: the paper's MADD accumulates at 2b bits.  For b=8/16 the
 int32 JAX path is exact; for b=32 we run the accumulation under
-``jax.experimental.enable_x64`` (int64), mirroring the 64-bit accumulator.
+``jax.enable_x64`` (int64), mirroring the 64-bit accumulator.
 """
 from __future__ import annotations
 
@@ -118,7 +118,7 @@ def eval_fixed_point(x, qt: QuantizedPWLTable):
     2b-bit MADD accumulate, de-quantize output."""
     lo, hi = _INT_INFO[qt.bits]
     if qt.bits == 32:
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             xq = jnp.clip(jnp.round(jnp.asarray(np.asarray(x, np.float64)) / qt.s_x), lo, hi).astype(jnp.int64)
             idx = jnp.sum(xq[..., None] > jnp.asarray(qt.bp_q), axis=-1)
             m = jnp.take(jnp.asarray(qt.m_q), idx)

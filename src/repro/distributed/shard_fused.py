@@ -23,9 +23,10 @@ shared by every fused dispatch point (``models/layers.py``, ``models/moe.py``,
 No psums are introduced anywhere fused math is head- or feature-local
 (attention per head, GLU per d_ff column); the only collectives are the ones
 the unfused math already performs (the MoE expert-parallel combine in
-``models/moe.py``).  ``check_rep=False`` everywhere: fused outputs may be
-replicated over mesh axes the specs don't mention, and shard_map's
-replication checker cannot see through a pallas_call anyway.
+``models/moe.py``).  :data:`shard_map` is ``jax.shard_map`` with
+``check_vma=False``, and every per-shard body in the repo uses it: fused
+outputs may be replicated over mesh axes the specs don't mention, and the
+varying-axes checker cannot see through a pallas_call anyway.
 
 Gradients differentiate straight through these wrappers: the fused ops are
 ``jax.custom_vjp``s, so shard_map transposes them per-shard — the fused
@@ -40,17 +41,15 @@ Design doc: docs/distributed.md.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 from jax.sharding import PartitionSpec as P
 
-try:  # jax>=0.4.35 re-export
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    from jax.shard_map import shard_map  # type: ignore
-
 from .sharding import Rules, logical_extent, spec_axes
+
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def dim_entry(rules: Rules, logical_axis: Optional[str], dim: int):
@@ -85,7 +84,6 @@ def sharded_call(rules: Rules, in_specs, out_specs):
             mesh=rules.mesh,
             in_specs=in_specs,
             out_specs=out_specs,
-            check_rep=False,
         )
 
     return wrap

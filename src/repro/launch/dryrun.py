@@ -73,8 +73,6 @@ def _compile_cell(cfg, mesh, cell, microbatches=None):
 
 def _metrics(compiled) -> dict:
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, list):  # older jax: list of per-computation dicts
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = hlo_parse.collective_bytes(hlo)
     return {

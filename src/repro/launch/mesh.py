@@ -13,6 +13,16 @@ traffic is exactly the data-parallel gradient reduction (DCI-friendly), while
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with Auto axes, so the sharding rules'
+    ``with_sharding_constraint`` calls may name every axis (``jax.make_mesh``
+    alone gives Explicit axes, which reject them)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -23,7 +33,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     need = math.prod(shape)
     have = len(jax.devices())
     if have == need:
-        return jax.make_mesh(shape, axes)
+        return make_mesh(shape, axes)
     if have < need:
         raise RuntimeError(
             f"need {need} devices for mesh {dict(zip(axes, shape))}, have {have} "
@@ -42,4 +52,4 @@ def make_host_mesh(model: int = 1):
     """Tiny mesh over however many devices this host exposes (tests/examples)."""
     n = len(jax.devices())
     assert n % model == 0, (n, model)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

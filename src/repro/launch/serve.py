@@ -63,14 +63,14 @@ def generate(model: Model, params, prompts: jnp.ndarray, max_new: int = 32):
     return jnp.concatenate(out, axis=1)
 
 
-def _serve_dense(model: Model, params, prompts: np.ndarray, args) -> int:
+def _serve_dense(model: Model, params, prompts: np.ndarray, args) -> dict:
     t0 = time.time()
     toks = generate(model, params, jnp.asarray(prompts), max_new=args.max_new)
     dt = time.time() - t0
     n = len(prompts) * args.max_new
     print(f"[serve] generated {n} tokens in {dt:.2f}s ({n/dt:.1f} tok/s)")
     print("[serve] sample:", np.asarray(toks[0, :12]))
-    return 0
+    return {"rc": 0, "mode": "dense", "seconds": dt}
 
 
 def _make_engine(model: Model, params, args, *, policy=None, guard=None,
@@ -107,7 +107,11 @@ def _print_health(health: dict) -> None:
         print(f"[serve] incident: {inc}")
 
 
-def _serve_paged(model: Model, params, prompts: np.ndarray, args) -> int:
+def _serve_paged(model: Model, params, prompts: np.ndarray, args) -> dict:
+    """One continuous-batching session.  Returns ``rc`` (1 on any fused
+    fallback), the ``engine`` (its jitted steps, cache and health stay
+    inspectable), the ``results``, the session's wall ``seconds`` and the
+    fallback ``warnings``."""
     from repro.serving import GenRequest, UnsupportedCacheError
 
     try:
@@ -147,13 +151,13 @@ def _serve_paged(model: Model, params, prompts: np.ndarray, args) -> int:
     print("[serve] sample:", by_id["req0"].tokens[:12])
     _print_health(engine.health_summary())
     print(f"[serve] fused fallbacks during session: {len(fallbacks)}")
-    if fallbacks:
-        # a fused plan that silently fell back mid-session is a perf
-        # regression CI must catch, not a warning to scroll past
-        for msg in fallbacks:
-            print(f"[serve]   fallback: {msg}", file=sys.stderr)
-        return 1
-    return 0
+    # a fused plan that silently fell back mid-session is a perf
+    # regression CI must catch, not a warning to scroll past
+    for msg in fallbacks:
+        print(f"[serve]   fallback: {msg}", file=sys.stderr)
+    return {"rc": 1 if fallbacks else 0, "mode": "paged", "engine": engine,
+            "requests": requests, "results": results, "seconds": dt,
+            "warnings": fallbacks}
 
 
 def _serve_chaos(model: Model, params, prompts: np.ndarray, args, cfg) -> int:
@@ -240,7 +244,9 @@ def _serve_chaos(model: Model, params, prompts: np.ndarray, args, cfg) -> int:
     return 0
 
 
-def serve(argv=None):
+def run(argv=None) -> dict:
+    """Parse ``argv`` and serve; returns the session record (``rc``,
+    ``mode``, and for ``--mode paged`` the engine and its results)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="repro-100m")
     ap.add_argument("--reduced", action="store_true")
@@ -321,10 +327,18 @@ def serve(argv=None):
 
     if args.mode == "paged":
         if args.chaos is not None:
-            return _serve_chaos(model, params, prompts, args, cfg)
+            return {"rc": _serve_chaos(model, params, prompts, args, cfg),
+                    "mode": "chaos"}
         return _serve_paged(model, params, prompts, args)
     return _serve_dense(model, params, prompts, args)
 
 
+def serve(argv=None) -> int:
+    return run(argv)["rc"]
+
+
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(serve())

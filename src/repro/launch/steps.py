@@ -209,7 +209,7 @@ def build_train_step(cfg, mesh, cell: ShapeCell, opt_cfg: Optional[adamw.AdamWCo
         in_shardings,
         out_shardings,
         (state_structs, bstructs),
-        dict(donate_argnums=(0,)),
+        dict(donate_argnums=(0,), rules=rules),
     )
 
 

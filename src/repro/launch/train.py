@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import argparse
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +29,10 @@ from repro.models import ShapeCell
 from repro.optim import adamw
 
 
-def train(argv=None):
+def run(argv=None) -> dict:
+    """Parse ``argv`` and train; returns ``rc`` (0 when the loss fell),
+    the per-step ``losses`` and ``step_seconds``, and the jitted ``step``
+    with its argument shapes ``structs``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="repro-100m")
     ap.add_argument("--reduced", action="store_true", help="reduced config (CI)")
@@ -100,6 +104,7 @@ def train(argv=None):
     jstep = jax.jit(step_fn, in_shardings=in_sh, out_shardings=out_sh,
                     donate_argnums=extra["donate_argnums"])
 
+    from repro.distributed.sharding import use_rules
     from repro.models import Model
 
     model = Model(cfg)
@@ -112,14 +117,16 @@ def train(argv=None):
     state = None
     it_state = None
     if ckpt and ckpt.latest_step() is not None:
-        params = model.init(jax.random.PRNGKey(0))
+        with use_rules(extra["rules"]):
+            params = model.init(jax.random.PRNGKey(0))
         proto = adamw.init_state(params)
         state, extra_meta = ckpt.restore(like=proto)
         start_step = int(extra_meta.get("step", 0))
         it_state = IteratorState.from_dict(extra_meta["iterator"]) if "iterator" in extra_meta else None
         print(f"[train] resumed from step {start_step}", flush=True)
     if state is None:
-        params = model.init(jax.random.PRNGKey(0))
+        with use_rules(extra["rules"]):
+            params = model.init(jax.random.PRNGKey(0))
         state = adamw.init_state(params)
 
     it = PrefetchIterator(data, state=it_state)
@@ -132,13 +139,15 @@ def train(argv=None):
 
     install_sigterm_save(emergency_save)
 
-    losses = []
+    losses, step_seconds = [], []
     for step in range(start_step, args.steps):
         batch = next(it)
         batch = {k: jnp.asarray(v) for k, v in batch.items()}
         monitor.start_step()
+        t0 = time.perf_counter()
         state, metrics = jstep(state, batch)
         loss = float(metrics["loss"])
+        step_seconds.append(time.perf_counter() - t0)
         monitor.end_step(step)
         losses.append(loss)
         if step % args.log_every == 0 or step == args.steps - 1:
@@ -152,13 +161,22 @@ def train(argv=None):
         if monitor.should_evict:
             print("[train] persistent straggler: checkpoint + exit for reschedule", flush=True)
             emergency_save()
-            return 17
+            return {"rc": 17, "losses": losses, "step_seconds": step_seconds,
+                    "step": jstep, "structs": structs}
     if ckpt:
         ckpt.save(args.steps, state, extra={"step": args.steps, "iterator": it.state.to_dict()})
     it.close()
     print(f"[train] done. first loss {losses[0]:.4f} -> last {losses[-1]:.4f}", flush=True)
-    return 0 if losses[-1] < losses[0] else 2
+    return {"rc": 0 if losses[-1] < losses[0] else 2, "losses": losses,
+            "step_seconds": step_seconds, "step": jstep, "structs": structs}
+
+
+def train(argv=None) -> int:
+    return run(argv)["rc"]
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(train())

@@ -157,6 +157,12 @@ class ParamDef:
     logical_axes: tuple[Optional[str], ...]
     init: str = "normal"      # normal | zeros | ones | small_normal
     dtype: Any = jnp.float32  # master dtype (cast to cfg.dtype in forward)
+    # inputs summed into each output ("normal" init draws std 1/sqrt(fan_in));
+    # None means shape[-2], right for (.., in, out) matrices.  Attention
+    # projections (D, H, dh) / (H, dh, D) set it: their shape[-2] is a head
+    # count, which draws q/k ~sqrt(D/H) too large, saturates every softmax
+    # row and makes the random model chaotic in its rounding.
+    fan_in: Optional[int] = None
 
     def initializer(self, key, fan_in: Optional[int] = None):
         if self.init == "zeros":
@@ -177,7 +183,7 @@ def init_params(defs, rng) -> Any:
     keys = jax.random.split(rng, len(leaves))
     vals = []
     for k, d in zip(keys, leaves):
-        fan_in = d.shape[-2] if len(d.shape) >= 2 else None
+        fan_in = d.fan_in or (d.shape[-2] if len(d.shape) >= 2 else None)
         vals.append(d.initializer(k, fan_in))
     return jax.tree_util.tree_unflatten(treedef, vals)
 

@@ -593,8 +593,9 @@ def _sliced_q_attention(cfg, q, k, v, *, causal, window, exp_fn, rules):
     all-gather vs tp K/V rotations.)"""
     import functools as _ft
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
+
+    from repro.distributed.shard_fused import shard_map
 
     mesh = rules.mesh
     tp = dict(mesh.shape).get("model", 1)
@@ -611,7 +612,6 @@ def _sliced_q_attention(cfg, q, k, v, *, causal, window, exp_fn, rules):
         mesh=mesh,
         in_specs=(P(bspec, None, None, None),) * 3,
         out_specs=P(bspec, None, None, None),
-        check_rep=False,
     )
     def run(q_r, k_r, v_r):
         r = jax.lax.axis_index("model")

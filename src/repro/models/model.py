@@ -21,9 +21,18 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
+
+from repro.distributed.sharding import active_rules, sanitize_spec
 
 from . import encdec, transformer
-from .common import ModelConfig, init_params, logical_specs, shape_structs
+from .common import (
+    ModelConfig,
+    ParamDef,
+    init_params,
+    logical_specs,
+    shape_structs,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,6 +49,24 @@ SHAPE_CELLS = {
     "decode_32k": ShapeCell("decode_32k", 32768, 128, "decode"),
     "long_500k": ShapeCell("long_500k", 524288, 1, "decode"),
 }
+
+
+def materialize(defs, rng):
+    """Real arrays for a ParamDef tree.  Under active rules with a mesh they
+    are made under ``jit`` straight into their sharded layout (the rules
+    applied to each leaf's logical axes), so no leaf ever exists whole on
+    one device."""
+    rules = active_rules()
+    if rules is None or rules.mesh is None:
+        return init_params(defs, rng)
+    shardings = jax.tree_util.tree_map(
+        lambda d: NamedSharding(
+            rules.mesh,
+            sanitize_spec(rules.mesh, rules.spec(*d.logical_axes), d.shape)),
+        defs, is_leaf=lambda x: isinstance(x, ParamDef),
+    )
+    return jax.jit(lambda r: init_params(defs, r),
+                   out_shardings=shardings)(rng)
 
 
 class Model:
@@ -61,7 +88,9 @@ class Model:
         return transformer.model_defs(self.cfg)
 
     def init(self, rng):
-        return init_params(self.param_defs(), rng)
+        """Real parameters, made sharded under active rules (see
+        :func:`materialize`)."""
+        return materialize(self.param_defs(), rng)
 
     def param_structs(self):
         return shape_structs(self.param_defs())
@@ -98,13 +127,17 @@ class Model:
 
     # -- paged serving (repro.serving; decoder-only transformers) -----------
     def make_paged_cache(self, num_pages: int, page_size: int):
+        """Zeroed paged KV pools, made sharded under active rules (see
+        :func:`materialize`)."""
         if self.cfg.is_encoder_decoder:
             from repro.serving.resilience import UnsupportedCacheError
 
             raise UnsupportedCacheError(
                 "paged serving covers decoder-only models"
             )
-        return transformer.make_paged_cache(self.cfg, num_pages, page_size)
+        return materialize(
+            transformer.paged_cache_defs(self.cfg, num_pages, page_size),
+            jax.random.PRNGKey(0))
 
     def prefill_paged(self, params, tokens, cache, page_table, lengths):
         return transformer.prefill_paged(

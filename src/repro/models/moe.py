@@ -1,7 +1,7 @@
 """Mixture-of-Experts FFN: token-choice top-k routing with capacity buckets.
 
-Dispatch is the cumsum-of-one-hot scheme (no sort, no double-batched gather —
-see repro/_jax_compat.py for why that matters here): every (token, choice)
+Dispatch is the cumsum-of-one-hot scheme (no sort, no double-batched
+gather): every (token, choice)
 pair gets a position within its expert's capacity bucket; overflow tokens are
 dropped (residual passes through).
 
@@ -30,10 +30,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import sfu
+from repro.distributed.shard_fused import shard_map
 from repro.distributed.sharding import _ACTIVE, constrain
 
 from .common import ModelConfig
@@ -112,7 +112,6 @@ def _moe_layer_shardmap(cfg: ModelConfig, params, x, rules, act,
         mesh=mesh,
         in_specs=(P(x_bspec, None, None), pspecs),
         out_specs=(P(x_bspec, None, None), P()),
-        check_rep=False,
     )
     def run(x_loc, p_loc):
         y, aux = _moe_local_dispatch(

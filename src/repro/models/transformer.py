@@ -24,6 +24,7 @@ API (all pure functions over a params pytree):
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Optional
 
@@ -57,10 +58,10 @@ def norm_defs(cfg: ModelConfig, d=None):
 def attn_defs(cfg: ModelConfig):
     D, H, Hkv, dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     defs = {
-        "wq": ParamDef((D, H, dh), ("embed", "heads", None)),
-        "wk": ParamDef((D, Hkv, dh), ("embed", "kv", None)),
-        "wv": ParamDef((D, Hkv, dh), ("embed", "kv", None)),
-        "wo": ParamDef((H, dh, D), ("heads", None, "embed")),
+        "wq": ParamDef((D, H, dh), ("embed", "heads", None), fan_in=D),
+        "wk": ParamDef((D, Hkv, dh), ("embed", "kv", None), fan_in=D),
+        "wv": ParamDef((D, Hkv, dh), ("embed", "kv", None), fan_in=D),
+        "wo": ParamDef((H, dh, D), ("heads", None, "embed"), fan_in=H * dh),
     }
     if cfg.qkv_bias:
         defs["bq"] = ParamDef((H, dh), ("heads", None), init="zeros")
@@ -126,7 +127,8 @@ def block_defs(cfg: ModelConfig, mixer: str, ffn: str):
 def _stack_defs(defs, n: int):
     """Prepend a (n,) scan axis ("layers") to every leaf ParamDef."""
     return jax.tree_util.tree_map(
-        lambda p: ParamDef((n,) + p.shape, ("layers",) + p.logical_axes, p.init, p.dtype),
+        lambda p: dataclasses.replace(p, shape=(n,) + p.shape,
+                                      logical_axes=("layers",) + p.logical_axes),
         defs,
         is_leaf=lambda x: isinstance(x, ParamDef),
     )
@@ -378,10 +380,12 @@ def decode_step(cfg: ModelConfig, params, tokens, cache, pos):
 # paged serving entry points (repro.serving)
 
 
-def make_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int):
-    """Per-layer paged KV pools (serving).  Same pytree structure the scan
+def paged_cache_defs(cfg: ModelConfig, num_pages: int, page_size: int):
+    """ParamDef-style tree for the per-layer paged KV pools (serving; zeros
+    init).  Same pytree structure the scan
     expects — one {k_pages, v_pages} dict per period slot, each leaf stacked
-    (n_periods, Hkv, num_pages, page_size, dh) — but the pools are SHARED
+    (n_periods, Hkv, num_pages, page_size, dh) and sharded over KV heads
+    ("cache_kv") — but the pools are SHARED
     across requests through a page table rather than sliced per batch row.
     Paged serving covers global-attention stacks only (ring-buffer local
     layers and SSM states have no paged layout); mixed stacks raise the
@@ -401,12 +405,10 @@ def make_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int):
         raise ValueError("num_pages must be >= 2 (page 0 is the sentinel)")
     n_periods = cfg.n_layers // cfg.period
     Hkv, dh = cfg.n_kv_heads, cfg.resolved_head_dim
-    shape = (n_periods, Hkv, num_pages, page_size, dh)
-    return [
-        {"k_pages": jnp.zeros(shape, cfg.dtype),
-         "v_pages": jnp.zeros(shape, cfg.dtype)}
-        for _ in range(cfg.period)
-    ]
+    pool = ParamDef((n_periods, Hkv, num_pages, page_size, dh),
+                    ("layers", "cache_kv", None, None, None), init="zeros",
+                    dtype=cfg.dtype)
+    return [{"k_pages": pool, "v_pages": pool} for _ in range(cfg.period)]
 
 
 def prefill_paged(cfg: ModelConfig, params, tokens, cache, page_table,

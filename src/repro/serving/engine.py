@@ -122,7 +122,8 @@ class PagedServingEngine:
         if num_pages is None:
             # worst case: every slot at max_context, plus the sentinel
             num_pages = max_slots * self.max_cols + 1
-        self.cache = model.make_paged_cache(num_pages, page_size)
+        with use_rules(rules):
+            self.cache = model.make_paged_cache(num_pages, page_size)
         self.sched = ContinuousBatchingScheduler(
             max_slots, page_size, num_pages, policy=policy,
             max_preemptions=max_preemptions, faults=faults,

@@ -30,9 +30,9 @@ re-materialize the cache):
   each step copies one full page of fresh K/V into the pool page the
   (scalar-prefetched) page table names.  Full-block writes, no read-back.
 * :func:`append_kv` — decode: grid ``(B, Hkv)``, each step read-modify-
-  writes ONE page: copy the resident page, overwrite row ``kv_len % ps``
-  with the new token's K/V.  One page per (request, head) per step is the
-  whole write traffic.
+  writes ONE page as a whole tile: the resident page with row
+  ``kv_len % ps`` replaced by the new token's K/V.  One page per (request,
+  head) per step is the whole write traffic.
 
 Validity is always a *position* prefix (``kv_len`` per request) even when
 the page IDs are fragmented — fragmentation lives entirely in the table's
@@ -212,13 +212,13 @@ def _write_prompt_pages(k_pages, v_pages, k_new, v_new, page_table, *,
 def _append_kernel(pidx_ref, slot_ref, nk_ref, nv_ref, kin_ref, vin_ref,
                    ko_ref, vo_ref):
     del pidx_ref  # consumed by the index maps
-    b = pl.program_id(0)
-    s = slot_ref[b]
-    # read-modify-write the one resident page: copy, then overwrite one row
-    ko_ref[...] = kin_ref[...]
-    vo_ref[...] = vin_ref[...]
-    ko_ref[0, 0, pl.ds(s, 1), :] = nk_ref[0, 0]
-    vo_ref[0, 0, pl.ds(s, 1), :] = nv_ref[0, 0]
+    s = slot_ref[pl.program_id(0)]
+    # read-modify-write the one resident page as a whole tile: select the new
+    # row over the page by iota.  A one-row store at a dynamic slot is not
+    # sublane-aligned, and Mosaic refuses it.
+    rows = jax.lax.broadcasted_iota(jnp.int32, ko_ref.shape[2:], 0)
+    ko_ref[0, 0] = jnp.where(rows == s, nk_ref[0, 0], kin_ref[0, 0])
+    vo_ref[0, 0] = jnp.where(rows == s, nv_ref[0, 0], vin_ref[0, 0])
 
 
 def append_kv(k_pages, v_pages, k_new, v_new, page_table, kv_len, *,

@@ -59,7 +59,7 @@ class RequestRejected(ServingError):
 class UnsupportedCacheError(ServingError, ValueError):
     """The model's layer stack cannot back a paged KV cache.
 
-    Raised by ``models.transformer.make_paged_cache`` for sliding-window /
+    Raised by ``Model.make_paged_cache`` for sliding-window /
     SSM / encoder-decoder stacks.  Front-ends should catch this and fall
     back to dense-mode decoding.
     """

@@ -12,19 +12,20 @@ def test_compressed_grad_sync_matches_exact_psum():
     r = run_py("""
         import jax, jax.numpy as jnp, numpy as np, functools
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from repro.distributed.shard_fused import shard_map
         import repro
+        from repro.launch.mesh import make_mesh
         from repro.distributed import compression
 
-        mesh = jax.make_mesh((8,), ("dp",))
+        mesh = make_mesh((8,), ("dp",))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64)) * 0.01
 
-        @functools.partial(shard_map, mesh=mesh, in_specs=P("dp"), out_specs=P(), check_rep=False)
+        @functools.partial(shard_map, mesh=mesh, in_specs=P("dp"), out_specs=P())
         def sync(gs):
             mean, res = compression.compressed_psum_leaf(gs[0], "dp")
             return mean
 
-        @functools.partial(shard_map, mesh=mesh, in_specs=P("dp"), out_specs=P(), check_rep=False)
+        @functools.partial(shard_map, mesh=mesh, in_specs=P("dp"), out_specs=P())
         def exact(gs):
             return jax.lax.pmean(gs[0], "dp")
 
@@ -43,15 +44,16 @@ def test_error_feedback_reduces_bias_over_steps():
     r = run_py("""
         import jax, jax.numpy as jnp, numpy as np, functools
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from repro.distributed.shard_fused import shard_map
         import repro
+        from repro.launch.mesh import make_mesh
         from repro.distributed import compression
 
-        mesh = jax.make_mesh((8,), ("dp",))
+        mesh = make_mesh((8,), ("dp",))
         # constant per-worker gradients: EF must recover the exact mean in sum
         g = jax.random.normal(jax.random.PRNGKey(1), (8, 32))
 
-        @functools.partial(shard_map, mesh=mesh, in_specs=(P("dp"), P("dp")), out_specs=(P(), P("dp")), check_rep=False)
+        @functools.partial(shard_map, mesh=mesh, in_specs=(P("dp"), P("dp")), out_specs=(P(), P("dp")))
         def step(gs, res):
             mean, new_res = compression.compressed_psum_leaf(gs[0] + res[0], "dp")
             return mean, new_res[None]
@@ -110,6 +112,7 @@ def test_moe_expert_parallel_2dev():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         import repro
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_reduced_config
         from repro.models import Model
         from repro.distributed.sharding import make_rules, use_rules
@@ -122,7 +125,7 @@ def test_moe_expert_parallel_2dev():
         batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)}
         ref, _ = model.forward(params, batch)
 
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         rules = make_rules(cfg, mesh)
         def fwd(p, b):
             with use_rules(rules):

@@ -20,6 +20,7 @@ import pytest
 import repro  # noqa: F401
 from repro import sfu
 from repro.kernels import fused
+from repro.launch.mesh import make_mesh
 from repro.models import layers
 
 BOUNDS = {"f32": 1e-5, "bf16": 0.08, "f16": 0.02}
@@ -284,9 +285,12 @@ def test_native_operands_through_fused_kernels(tdtype):
     t = sfu.get_store().get(fn="exp", n_breakpoints=32, dtype=tdtype)
     x = _rand(0, (16, 256), scale=3.0) - 2.0
     y_native = ops.pwl_activation(x, t)
-    # force the legacy packing through the same kernel body
+    # force the legacy packing through the same kernel body, compiled as the
+    # kernel is: op by op, XLA contracts the value's FMA chain differently
+    # and the two differ by an ulp
     bp_u, dmq_u = fused.pack_table(t, native=False)
-    y_upcast, _ = fused.pwl_value_and_slope_tile(x, bp_u, dmq_u, 32)
+    y_upcast, _ = jax.jit(
+        lambda x: fused.pwl_value_and_slope_tile(x, bp_u, dmq_u, 32))(x)
     np.testing.assert_allclose(np.asarray(y_native), np.asarray(y_upcast),
                                atol=1e-7, rtol=1e-7)
     # flash attention with a native table runs one pallas_call and stays
@@ -398,7 +402,7 @@ def test_one_device_mesh_keeps_fused_and_never_warns():
     exp_fn = layers.resolve_exp(cfg, plan)
     q, k, v = _qkv(14, S=16, H=cfg.n_heads, Hkv=cfg.n_kv_heads,
                    dh=cfg.resolved_head_dim)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = make_rules(cfg, mesh)
     sfu.reset_fused_fallback_warnings()
     with use_rules(rules):

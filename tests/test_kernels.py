@@ -115,9 +115,22 @@ else:
 def test_pwl_softmax_ref_close_to_exact():
     table = sfu.get_store().get(fn="exp", n_breakpoints=32)
     x = jax.random.normal(jax.random.PRNGKey(3), (4, 128)) * 3
+    n = x.shape[-1]
     approx = ref.pwl_softmax_ref(x, table)
     exact = jax.nn.softmax(x, axis=-1)
-    np.testing.assert_allclose(approx, exact, atol=2e-3)
+    # delta: the table's own max |PWL(exp) - exp| over the shifted inputs'
+    # domain [min(x - max), 0], on a dense grid
+    grid = jnp.linspace(float(jnp.min(x - jnp.max(x, -1, keepdims=True))),
+                        0.0, 100_001)
+    e_grid = jnp.maximum(ref.pwl_activation_ref(grid, table), 0.0)
+    delta = float(jnp.max(jnp.abs(e_grid - jnp.exp(grid))))
+    assert delta < 1e-3, delta
+    # With e'_j = e_j + d_j, |d_j| <= delta and the row max giving
+    # sum(e') >= 1 - delta: |p'_i - p_i| <= (|d_i| + p_i |sum d|) / sum(e')
+    #                                     <= delta (1 + n p_i) / (1 - delta)
+    bound = delta * (1.0 + n * exact) / (1.0 - delta)
+    assert bool(jnp.all(jnp.abs(approx - exact) <= bound)), float(
+        jnp.max(jnp.abs(approx - exact) - bound))
     np.testing.assert_allclose(jnp.sum(approx, -1), 1.0, rtol=1e-5)
 
 

@@ -47,11 +47,12 @@ class TestHLOParse:
             [sys.executable, "-c", textwrap.dedent("""
                 import jax, jax.numpy as jnp, functools
                 from jax.sharding import PartitionSpec as P
-                from jax.experimental.shard_map import shard_map
+                from repro.distributed.shard_fused import shard_map
                 import repro
+                from repro.launch.mesh import make_mesh
                 from repro.roofline import hlo_parse
 
-                mesh = jax.make_mesh((4,), ("d",))
+                mesh = make_mesh((4,), ("d",))
                 @functools.partial(shard_map, mesh=mesh, in_specs=P("d"), out_specs=P())
                 def f(x):
                     return jax.lax.psum(x.sum(0), "d")

@@ -8,14 +8,15 @@ Multi-device scenarios run in subprocesses (tests/mesh_utils.py) so the
 rest of the suite keeps seeing one device; in-process tests cover the
 1-device-mesh predicate and the sanitize_spec warn-once lifecycle.
 """
+import textwrap
 import warnings
 
-import jax
 import pytest
 
 import repro  # noqa: F401
 from repro import sfu
 from repro.distributed import shard_fused, sharding
+from repro.launch.mesh import make_mesh
 
 from mesh_utils import run_py
 
@@ -67,49 +68,78 @@ def test_train_step_2x2_mesh_zero_fallbacks():
     assert "OK" in r.stdout
 
 
+# One train step on a 2x2 mesh under warnings-as-errors, with the fused
+# Pallas backward (impl_bwd="fused", the default) or the jnp recompute
+# oracle; ``one_step`` returns the AdamW state after it and the loss.
+_GRAD_PARITY_PRELUDE = """
+    import warnings
+    warnings.filterwarnings("error", message=".*falling back.*")
+    import jax, jax.numpy as jnp, numpy as np
+    import repro
+    from repro.configs import get_reduced_config
+    from repro.kernels import fused
+    from repro.launch.mesh import make_host_mesh, make_mesh
+    from repro.launch.steps import build_train_step
+    from repro.models import Model, ShapeCell
+    from repro.optim import adamw
+
+    cfg = get_reduced_config("repro-100m", act_impl="fused",
+                             pwl_softmax=True, force_dp_only=False,
+                             dtype=jnp.DTYPE)
+    cell = ShapeCell("t", 64, 4, "train")
+    model = Model(cfg)
+    batch = {
+        "tokens": jax.random.randint(
+            jax.random.PRNGKey(1), (4, 64), 0, cfg.vocab_size),
+        "targets": jax.random.randint(
+            jax.random.PRNGKey(2), (4, 64), 0, cfg.vocab_size),
+    }
+
+    def one_step(mesh, impl_bwd):
+        fn, in_sh, out_sh, structs, extra = build_train_step(
+            cfg, mesh, cell, microbatches=1)
+        # use_impl_bwd is read at TRACE time: wrap the jit execution
+        with fused.use_impl_bwd(impl_bwd):
+            jstep = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh)
+            state = adamw.init_state(model.init(jax.random.PRNGKey(0)))
+            state, metrics = jstep(state, batch)
+        return jax.device_get(state), float(metrics["loss"])
+
+    def grad_rel(s, s_oracle):
+        # The gradient is read from AdamW's first moment after step one
+        # (0.1 * g); the updated parameters would not do, since step one
+        # moves each by ~lr * sign(g) and a rounding-level difference flips
+        # the sign of a near-zero g.  Per leaf, relative to its largest
+        # gradient.
+        return max(
+            float(np.max(np.abs(np.asarray(x, np.float32)
+                                - np.asarray(y, np.float32)))
+                  / np.max(np.abs(np.asarray(y, np.float32))))
+            for x, y in zip(jax.tree_util.tree_leaves(s["mu"]),
+                            jax.tree_util.tree_leaves(s_oracle["mu"]))
+            if np.max(np.abs(y)) > 0)
+"""
+
+
+def _run_grad_parity(dtype: str, checks: str):
+    code = (textwrap.dedent(_GRAD_PARITY_PRELUDE).replace("DTYPE", dtype)
+            + textwrap.dedent(checks))
+    return run_py(code, devices=4)
+
+
 def test_train_step_2x2_mesh_fused_backward_grad_parity():
     """ISSUE 9 mesh acceptance: a full train step whose gradients flow
     through the fused Pallas BACKWARD kernels (impl_bwd="fused" is the
-    default) on a 2x2 mesh, warnings-as-errors — zero fallbacks — and the
-    updated parameters match (a) the same step on a 1-device mesh and
-    (b) the jnp-recompute backward oracle on the same 2x2 mesh."""
-    r = run_py("""
-        import warnings
-        warnings.filterwarnings("error", message=".*falling back.*")
-        import jax, jax.numpy as jnp, numpy as np
-        import repro
-        from repro.configs import get_reduced_config
-        from repro.kernels import fused
-        from repro.launch.mesh import make_host_mesh
-        from repro.launch.steps import build_train_step
-        from repro.models import Model, ShapeCell
-        from repro.optim import adamw
-
-        cfg = get_reduced_config("repro-100m", act_impl="fused",
-                                 pwl_softmax=True, force_dp_only=False)
-        cell = ShapeCell("t", 64, 4, "train")
-        model = Model(cfg)
-        batch = {
-            "tokens": jax.random.randint(
-                jax.random.PRNGKey(1), (4, 64), 0, cfg.vocab_size),
-            "targets": jax.random.randint(
-                jax.random.PRNGKey(2), (4, 64), 0, cfg.vocab_size),
-        }
-
-        def one_step(mesh, impl_bwd):
-            fn, in_sh, out_sh, structs, extra = build_train_step(
-                cfg, mesh, cell, microbatches=1)
-            # use_impl_bwd is read at TRACE time: wrap the jit execution
-            with fused.use_impl_bwd(impl_bwd):
-                jstep = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh)
-                state = adamw.init_state(model.init(jax.random.PRNGKey(0)))
-                state, metrics = jstep(state, batch)
-            return jax.device_get(state["params"]), float(metrics["loss"])
-
-        p_ref, l_ref = one_step(jax.make_mesh((1, 1), ("data", "model")),
+    default) on a 2x2 mesh, in the config's bf16, warnings-as-errors — zero
+    fallbacks — and (a) the updated parameters match the same step on a
+    1-device mesh, (b) the gradients match the jnp-recompute backward
+    oracle on the same 2x2 mesh to bf16 rounding."""
+    r = _run_grad_parity("bfloat16", """
+        s_ref, l_ref = one_step(make_mesh((1, 1), ("data", "model")),
                                 "fused")
-        p_mesh, l_mesh = one_step(make_host_mesh(model=2), "fused")
-        p_rec, l_rec = one_step(make_host_mesh(model=2), "recompute")
+        s_mesh, l_mesh = one_step(make_host_mesh(model=2), "fused")
+        s_rec, l_rec = one_step(make_host_mesh(model=2), "recompute")
+        p_ref, p_mesh = s_ref["params"], s_mesh["params"]
 
         def maxdiff(a, b):
             return max(
@@ -124,13 +154,36 @@ def test_train_step_2x2_mesh_fused_backward_grad_parity():
         assert abs(l_mesh - l_ref) < 1e-3 * abs(l_ref), (l_mesh, l_ref)
         d_mesh = maxdiff(p_mesh, p_ref)
         assert d_mesh < 1e-4, d_mesh
-        # fused vs recompute backward on the SAME mesh: near-bitwise
-        # (measured 5e-13) — the kernels compute the same gradient
+        # fused vs recompute backward on the SAME mesh: the same forward,
+        # and gradients from the same bf16 activations.  The kernels and
+        # the oracle round intermediates to bf16 (2^-8 relative) at other
+        # points, so they differ by a few bf16 roundings of the leaf's
+        # largest gradient (measured 5.7e-3); a wrong slope or block moves a
+        # gradient by its own scale.
         assert l_mesh == l_rec, (l_mesh, l_rec)
-        d_bwd = maxdiff(p_mesh, p_rec)
-        assert d_bwd < 1e-9, d_bwd
+        d_bwd = grad_rel(s_mesh, s_rec)
+        assert d_bwd < 2e-2, d_bwd
         print("OK", l_mesh, d_mesh, d_bwd)
-    """, devices=4)
+    """)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "OK" in r.stdout
+
+
+def test_train_step_2x2_mesh_fused_backward_grad_parity_f32():
+    """The fused-vs-recompute gradient check of the test above in f32,
+    where no bf16 rounding hides a fault: on a 2x2 mesh the fused Pallas
+    backward and the jnp oracle agree to f32 rounding."""
+    r = _run_grad_parity("float32", """
+        s_mesh, l_mesh = one_step(make_host_mesh(model=2), "fused")
+        s_rec, l_rec = one_step(make_host_mesh(model=2), "recompute")
+        assert jnp.isfinite(l_mesh), l_mesh
+        # the same forward; the kernels and the oracle sum in other orders,
+        # so the gradients agree to f32 rounding (measured 3e-7)
+        assert l_mesh == l_rec, (l_mesh, l_rec)
+        d_bwd = grad_rel(s_mesh, s_rec)
+        assert d_bwd < 1e-5, d_bwd
+        print("OK", l_mesh, d_bwd)
+    """)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "OK" in r.stdout
 
@@ -189,6 +242,7 @@ def test_moe_expert_parallel_fused_parity():
         warnings.filterwarnings("error", message=".*falling back.*")
         import jax, jax.numpy as jnp, numpy as np
         import repro
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_reduced_config
         from repro.distributed.sharding import make_rules, use_rules
         from repro.models import Model
@@ -202,7 +256,7 @@ def test_moe_expert_parallel_fused_parity():
         ref, _ = model.forward(params, batch)
 
         for shape in ((1, 2), (2, 2)):
-            mesh = jax.make_mesh(shape, ("data", "model"))
+            mesh = make_mesh(shape, ("data", "model"))
             rules = make_rules(cfg, mesh)
             def fwd(p, b):
                 with use_rules(rules):
@@ -225,6 +279,7 @@ def test_fused_glu_grad_parity_under_shard_map():
         import functools
         import jax, jax.numpy as jnp, numpy as np
         import repro
+        from repro.launch.mesh import make_mesh
         from repro import sfu
         from repro.core import pwl
         from repro.kernels import fused
@@ -234,7 +289,7 @@ def test_fused_glu_grad_parity_under_shard_map():
         class _Cfg:  # make_rules only reads head counts
             n_heads = 4
             n_kv_heads = 4
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         rules = make_rules(_Cfg, mesh)
         table = sfu.get_store().get(fn="silu", n_breakpoints=32)
 
@@ -278,6 +333,7 @@ def test_fused_rmsnorm_per_shard():
     r = run_py("""
         import jax, jax.numpy as jnp, numpy as np
         import repro
+        from repro.launch.mesh import make_mesh
         from repro import sfu
         from repro.kernels import fused
         from repro.distributed import shard_fused as shf
@@ -286,7 +342,7 @@ def test_fused_rmsnorm_per_shard():
         class _Cfg:
             n_heads = 4
             n_kv_heads = 4
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         rules = make_rules(_Cfg, mesh)
         table = sfu.get_store().get(fn="silu", n_breakpoints=32)
 
@@ -326,6 +382,7 @@ def test_engine_session_warns_once_per_session_on_seq_sharded_cache():
         import numpy as np
         import jax
         import repro
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_reduced_config
         from repro.distributed.sharding import make_rules
         from repro.models import Model
@@ -333,7 +390,7 @@ def test_engine_session_warns_once_per_session_on_seq_sharded_cache():
 
         cfg = get_reduced_config("repro-100m", act_impl="fused",
                                  pwl_softmax=True, force_dp_only=False)
-        mesh = jax.make_mesh((2, 3), ("data", "model"))
+        mesh = make_mesh((2, 3), ("data", "model"))
         rules = make_rules(cfg, mesh)
         # heads (4) don't divide model (3): seq-parallel rules, cache_seq
         # sharded over "model"
@@ -380,7 +437,7 @@ def test_active_mesh_rules_is_none_without_multi_device_mesh():
     with sharding.use_rules(bare):
         assert sharding.active_rules() is bare
         assert sharding.active_mesh_rules() is None
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = sharding.Rules(table={"batch": ("data",)},
                            mesh_axes=("data", "model"), mesh=mesh)
     with sharding.use_rules(rules):
@@ -391,7 +448,7 @@ def test_shard_spec_replicates_non_dividing_dims():
     """dim_entry/shard_spec: shard when the mesh extent divides the dim,
     replicate otherwise — the same escape hatch sanitize_spec applies to
     the unfused path (no warning, no error)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = sharding.Rules(
         table={"batch": ("data",), "mlp": "model", "act_heads": "model"},
         mesh_axes=("data", "model"), mesh=mesh)

@@ -15,6 +15,7 @@ import repro  # noqa: F401
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import DataConfig, IteratorState, PrefetchIterator, SyntheticLMData
 from repro.distributed.monitor import StepMonitor
+from repro.launch.mesh import make_mesh
 from repro.optim import adamw
 
 
@@ -108,7 +109,7 @@ class TestCheckpoint:
         mgr = CheckpointManager(tmp_path)
         state = {"w": jnp.arange(8.0)}
         mgr.save(1, state)
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         sh = {"w": NamedSharding(mesh, P("data"))}
         restored, _ = mgr.restore(like=state, shardings=sh)
         assert restored["w"].sharding == sh["w"]
