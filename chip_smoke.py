@@ -76,33 +76,14 @@ def check(cond: bool, what: str) -> None:
         raise Failed(what)
 
 
-class CompileMeter:
-    """Seconds of backend (XLA and Mosaic) compilation, the part the
-    persistent cache saves: a hit counts its retrieval time.  Tracing and
-    lowering are left out, since nested jits report them once per level."""
+def compile_s(sp) -> float:
+    """Seconds of compilation (tracing, lowering, backend compile or a
+    persistent-cache read) inside the closed span ``sp``, its own and its
+    children's (``repro.tracing`` gives each span the compiles it holds)."""
+    from repro import tracing
 
-    def __init__(self):
-        import jax
-
-        self.seconds = 0.0
-        self.cache_hits = 0
-
-        def on_duration(event, duration, **_):
-            if event == "/jax/core/compile/backend_compile_duration":
-                self.seconds += duration
-
-        def on_event(event, **_):
-            if event == "/jax/compilation_cache/cache_hits":
-                self.cache_hits += 1
-
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-        jax.monitoring.register_event_listener(on_event)
-
-    def mark(self):
-        return self.seconds, self.cache_hits
-
-    def since(self, mark):
-        return self.seconds - mark[0], self.cache_hits - mark[1]
+    return sum(r.attrs.get("compile_s", 0.0)
+               for r in tracing.records(since=sp.t0, until=sp.t1))
 
 
 def check_warnings(caught, phase: str) -> None:
@@ -213,12 +194,12 @@ def check_health(engine, phase: str) -> None:
 # phases
 
 
-def serve_phase(meter):
+def serve_phase():
     """olmo-1b paged serving through ``repro.launch.serve``."""
     import jax
     import numpy as np
 
-    from repro import sfu
+    from repro import sfu, tracing
     from repro.configs import get_config
     from repro.launch import serve
     from repro.models import Model
@@ -241,27 +222,27 @@ def serve_phase(meter):
             "--batch", str(requests), "--prompt-len", str(prompt_len),
             "--max-new", str(max_new), "--max-slots", str(slots),
             "--page-size", str(page_size)]
-    mark = meter.mark()
-    with warnings.catch_warnings(record=True) as caught:
+    with (tracing.span("chip_smoke.serve.cold") as sp,
+          warnings.catch_warnings(record=True) as caught):
         warnings.simplefilter("always")
         out = serve.run(argv)
     check_warnings(caught, "serve")
     check(out["rc"] == 0, f"serve: rc {out['rc']} (fused fallbacks "
           f"{out.get('warnings')})")
     check(out["mode"] == "paged", f"serve: ran {out['mode']!r}, not paged")
-    cold_compile, cold_hits = meter.since(mark)
+    cold_compile = compile_s(sp)
     engine = out["engine"]
     check_results(out["results"], requests, max_new, "serve")
     check_health(engine, "serve")
     cold = {r.request_id: r.tokens for r in out["results"]}
     log(f"serve: cold session {out['seconds']:.3f}s, compile "
-        f"{cold_compile:.3f}s ({cold_hits} persistent-cache hits)")
+        f"{cold_compile:.3f}s")
 
     prompts = [r.prompt for r in out["requests"]]
     warm_reqs = [GenRequest(f"warm{i}", p, max_new_tokens=max_new)
                  for i, p in enumerate(prompts)]
-    mark = meter.mark()
-    with warnings.catch_warnings(record=True) as caught:
+    with (tracing.span("chip_smoke.serve.warm") as sp,
+          warnings.catch_warnings(record=True) as caught):
         warnings.simplefilter("always")
         gen0 = engine.generated
         t0 = time.perf_counter()
@@ -270,7 +251,7 @@ def serve_phase(meter):
         warm_s = time.perf_counter() - t0
         tokens = engine.generated - gen0
     check_warnings(caught, "serve")
-    warm_compile, _ = meter.since(mark)
+    warm_compile = compile_s(sp)
     check_results(warm, requests, max_new, "serve warm")
     check_health(engine, "serve")
     for r in warm:
@@ -303,7 +284,7 @@ def serve_phase(meter):
             "worst_rel_dlogit": worst, "top1_agree": agree}
 
 
-def flash_phase(meter):
+def flash_phase():
     """The fused flash-attention kernel at olmo-1b's head widths, forward and
     fused backward.  The launchers pick it over the dense fused-softmax kernel
     only past ``layers.DENSE_FUSED_SOFTMAX_MAX_SCORES`` scores (prompts of
@@ -313,7 +294,7 @@ def flash_phase(meter):
     import jax.numpy as jnp
     import numpy as np
 
-    from repro import sfu
+    from repro import sfu, tracing
     from repro.configs import get_config
     from repro.kernels import fused
     from repro.models import layers
@@ -340,32 +321,31 @@ def flash_phase(meter):
         check(np.all(np.isfinite(a)), "flash: non-finite output")
         return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
 
-    mark = meter.mark()
-    jflash = jax.jit(flash)
-    y = jflash(q, k, v)
-    y_dense = jax.jit(lambda q, k, v: layers.dense_pwl_attention(
-        q, k, v, table=table, causal=True))(q, k, v)
-    d_fwd = rel(y, y_dense)
-    d_bwd = max(rel(a, b) for a, b in zip(grads("fused")(q, k, v),
-                                          grads("recompute")(q, k, v)))
-    compile_s, _ = meter.since(mark)
+    with tracing.span("chip_smoke.flash") as sp:
+        jflash = jax.jit(flash)
+        y = jflash(q, k, v)
+        y_dense = jax.jit(lambda q, k, v: layers.dense_pwl_attention(
+            q, k, v, table=table, causal=True))(q, k, v)
+        d_fwd = rel(y, y_dense)
+        d_bwd = max(rel(a, b) for a, b in zip(grads("fused")(q, k, v),
+                                              grads("recompute")(q, k, v)))
     n_fwd = pallas_calls(jflash, q, k, v)
     n_bwd = pallas_calls(grads("fused"), q, k, v)
     log(f"flash: {shape} bf16 causal: forward vs dense fused softmax "
         f"max|d|/max {d_fwd:.5g}, fused vs recompute backward {d_bwd:.5g} "
         f"(tolerance {FLASH_TOL_REL}); Pallas calls forward {n_fwd}, "
-        f"backward {n_bwd}; compile {compile_s:.3f}s")
+        f"backward {n_bwd}; compile {compile_s(sp):.3f}s")
     check(n_fwd >= 1 and n_bwd >= 2, "flash: too few Pallas calls")
     check(d_fwd <= FLASH_TOL_REL and d_bwd <= FLASH_TOL_REL,
           f"flash: disagreement beyond {FLASH_TOL_REL}")
     return {"fwd_rel": d_fwd, "bwd_rel": d_bwd}
 
 
-def train_phase(meter):
+def train_phase():
     """repro-100m training through ``repro.launch.train``."""
     import math
 
-    from repro import sfu
+    from repro import sfu, tracing
     from repro.configs import get_config
     from repro.launch import train
 
@@ -380,12 +360,12 @@ def train_phase(meter):
     argv = ["--arch", arch, "--steps", str(steps), "--batch", str(batch),
             "--seq", str(seq), "--plan", str(plan_path), "--impl-bwd",
             "fused", "--log-every", "1"]
-    mark = meter.mark()
-    with warnings.catch_warnings(record=True) as caught:
+    with (tracing.span("chip_smoke.train") as sp,
+          warnings.catch_warnings(record=True) as caught):
         warnings.simplefilter("always")
         out = train.run(argv)
     check_warnings(caught, "train")
-    compile_s, hits = meter.since(mark)
+    secs = compile_s(sp)
     losses = out["losses"]
     check(len(losses) == steps and all(math.isfinite(x) for x in losses),
           f"train: losses {losses}")
@@ -395,19 +375,18 @@ def train_phase(meter):
     check(n_step >= 2, f"train: the step holds {n_step} Pallas calls")
     warm = out["step_seconds"][1:]
     log(f"train: losses {[round(x, 5) for x in losses]}")
-    log(f"train: compile {compile_s:.3f}s ({hits} persistent-cache hits), "
-        f"warm step mean {sum(warm) / len(warm):.4f}s, Pallas calls in the "
+    log(f"train: compile {secs:.3f}s, warm step mean {sum(warm) / len(warm):.4f}s, Pallas calls in the "
         f"compiled step {n_step}")
-    return {"losses": losses, "compile_s": compile_s}
+    return {"losses": losses, "compile_s": secs}
 
 
-def mesh_phase(meter):
+def mesh_phase():
     """olmoe-1b-7b paged serving on a (data=1, model=4) mesh, sharded end to
     end."""
     import jax
     import numpy as np
 
-    from repro import sfu
+    from repro import sfu, tracing
     from repro.configs import get_config
     from repro.distributed.sharding import make_rules, use_rules
     from repro.launch.mesh import make_mesh
@@ -456,19 +435,16 @@ def mesh_phase(meter):
     pool = jax.tree_util.tree_leaves(engine.cache)[0]
     check(pool.addressable_shards[0].data.shape[1] == cfg.n_kv_heads // tp,
           f"mesh: KV pool not sharded over heads: {pool.sharding}")
-    mark = meter.mark()
-    with warnings.catch_warnings(record=True) as caught:
+    with (tracing.span("chip_smoke.mesh") as sp,
+          warnings.catch_warnings(record=True) as caught):
         warnings.simplefilter("always")
-        t0 = time.perf_counter()
         results = engine.run([GenRequest(f"r{i}", p, max_new_tokens=max_new)
                               for i, p in enumerate(prompts)])
-        dt = time.perf_counter() - t0
     check_warnings(caught, "mesh")
-    compile_s, hits = meter.since(mark)
     check_results(results, requests, max_new, "mesh")
     check_health(engine, "mesh")
-    log(f"mesh: session {engine.generated} tokens in {dt:.3f}s including "
-        f"compile {compile_s:.3f}s ({hits} persistent-cache hits)")
+    log(f"mesh: session {engine.generated} tokens in {sp.t1 - sp.t0:.3f}s "
+        f"including compile {compile_s(sp):.3f}s")
     n_dec = pallas_calls(
         engine._fns["decode"], engine.params,
         jax.numpy.zeros((slots, 1), jax.numpy.int32), engine.cache,
@@ -501,6 +477,7 @@ def main(argv=None) -> int:
 
     import jax
 
+    from repro import tracing
     from repro.kernels._backend import should_interpret
     from repro.launch.compile_cache import enable_compile_cache
 
@@ -523,22 +500,21 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 1
     OUT.mkdir(parents=True, exist_ok=True)
-    meter = CompileMeter()
-    t0 = time.perf_counter()
     try:
-        if args.chips == 4:
-            summary = {"mesh": mesh_phase(meter)}
-        else:
-            summary = {"serve": serve_phase(meter)}
-            log(f"peak HBM after serve (GiB): {peak_hbm_gib(devices[:1])}")
-            summary["flash"] = flash_phase(meter)
-            summary["train"] = train_phase(meter)
+        with tracing.span("chip_smoke") as sp:
+            if args.chips == 4:
+                summary = {"mesh": mesh_phase()}
+            else:
+                summary = {"serve": serve_phase()}
+                log(f"peak HBM after serve (GiB): {peak_hbm_gib(devices[:1])}")
+                summary["flash"] = flash_phase()
+                summary["train"] = train_phase()
         log(f"peak HBM (GiB): {peak_hbm_gib(devices[:args.chips])}")
     except Failed as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1
-    log(f"all phases passed in {time.perf_counter() - t0:.1f}s, compile "
-        f"{meter.seconds:.3f}s in all")
+    log(f"all phases passed in {sp.t1 - sp.t0:.1f}s, compile "
+        f"{compile_s(sp):.3f}s in all")
     (OUT / f"summary_{args.chips}chip.json").write_text(
         json.dumps({"device": device, **summary}, indent=1) + "\n")
     print(json.dumps({"ok": True, "device": device}))

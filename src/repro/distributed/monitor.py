@@ -50,8 +50,12 @@ class StepMonitor:
     def start_step(self):
         self._t0 = time.perf_counter()
 
-    def end_step(self, step: int) -> Optional[StragglerEvent]:
-        dt = time.perf_counter() - self._t0
+    def end_step(self, step: int,
+                 step_time: Optional[float] = None) -> Optional[StragglerEvent]:
+        """Close ``step``: its time is ``step_time`` when the caller timed it,
+        else the time since :meth:`start_step`."""
+        dt = (time.perf_counter() - self._t0 if step_time is None
+              else step_time)
         self.heartbeat(step)
         if len(self.window) >= 10:
             med = statistics.median(self.window)

@@ -13,12 +13,11 @@ from __future__ import annotations
 
 import argparse
 import sys
-import time
 
 import jax
 import jax.numpy as jnp
 
-from repro import sfu
+from repro import sfu, tracing
 from repro.checkpoint.manager import CheckpointManager, install_sigterm_save
 from repro.configs import get_config, get_reduced_config
 from repro.data.pipeline import DataConfig, IteratorState, PrefetchIterator, SyntheticLMData
@@ -143,12 +142,11 @@ def run(argv=None) -> dict:
     for step in range(start_step, args.steps):
         batch = next(it)
         batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        monitor.start_step()
-        t0 = time.perf_counter()
-        state, metrics = jstep(state, batch)
-        loss = float(metrics["loss"])
-        step_seconds.append(time.perf_counter() - t0)
-        monitor.end_step(step)
+        with tracing.span("train.step") as sp:
+            state, metrics = jstep(state, batch)
+            loss = float(metrics["loss"])
+        step_seconds.append(sp.t1 - sp.t0)
+        monitor.end_step(step, step_seconds[-1])
         losses.append(loss)
         if step % args.log_every == 0 or step == args.steps - 1:
             print(

@@ -69,7 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import sfu
+from repro import sfu, tracing
 from repro.distributed.sharding import use_rules
 from repro.models import Model
 
@@ -122,7 +122,7 @@ class PagedServingEngine:
         if num_pages is None:
             # worst case: every slot at max_context, plus the sentinel
             num_pages = max_slots * self.max_cols + 1
-        with use_rules(rules):
+        with use_rules(rules), tracing.span("serving.init"):
             self.cache = model.make_paged_cache(num_pages, page_size)
         self.sched = ContinuousBatchingScheduler(
             max_slots, page_size, num_pages, policy=policy,
@@ -158,7 +158,7 @@ class PagedServingEngine:
         rules = self.rules
         guard_on = self.guard
 
-        def wrap(fn):
+        def wrap(fn, phase: str):
             def call(params, toks, cache, pt, lens):
                 # trace-time contexts: rules activate the sharded dispatch,
                 # force_nan arms the fault hook, collecting() the counters
@@ -173,10 +173,20 @@ class PagedServingEngine:
                 diag = col.result() if col is not None else {}
                 return logits, new_cache, diag
 
-            return jax.jit(call)
+            # the name the profiler's trace and the compiled module carry
+            call.__name__ = call.__qualname__ = f"serving_{phase}"
+            jitted = jax.jit(call)
+            span_name = f"serving.{phase}.run"
 
-        return {"prefill": wrap(model.prefill_paged),
-                "decode": wrap(model.decode_step_paged)}
+            def run(params, toks, cache, pt, lens):
+                with tracing.span(span_name):
+                    return jitted(params, toks, cache, pt, lens)
+
+            run.lower = jitted.lower
+            return run
+
+        return {"prefill": wrap(model.prefill_paged, "prefill"),
+                "decode": wrap(model.decode_step_paged, "decode")}
 
     def _nan_fns(self, site: str):
         if site not in self._nan_fns_cache:
@@ -285,19 +295,24 @@ class PagedServingEngine:
         """Write the page-table row, run bucketed prefill, sample the first
         token (fresh requests) or resume the pre-preemption token (restores).
         Returns True when the request finished AT prefill."""
-        slot = adm.slot
-        toks_list = adm.prefill_tokens
-        n = len(toks_list)
+        n = len(adm.prefill_tokens)
         bucket = max(self.page_size, _next_pow2(n))
-        npg = bucket // self.page_size
-        row = np.zeros((self.max_cols,), np.int32)
-        row[: len(adm.pages)] = adm.pages
-        self.page_table[slot] = row
-        self.kv_len[slot] = n
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :n] = toks_list
-        args = (jnp.asarray(toks), self.cache,
-                jnp.asarray(row[None, :npg]), jnp.asarray([n], jnp.int32))
+        with tracing.span("serving.prefill", request_id=adm.request.request_id,
+                          tokens=n, bucket=bucket):
+            return self._prefill_bucket(adm, n, bucket)
+
+    def _prefill_bucket(self, adm: Admission, n: int, bucket: int) -> bool:
+        slot = adm.slot
+        with tracing.span("serving.prefill.inputs"):
+            npg = bucket // self.page_size
+            row = np.zeros((self.max_cols,), np.int32)
+            row[: len(adm.pages)] = adm.pages
+            self.page_table[slot] = row
+            self.kv_len[slot] = n
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :n] = adm.prefill_tokens
+            args = (jnp.asarray(toks), self.cache,
+                    jnp.asarray(row[None, :npg]), jnp.asarray([n], jnp.int32))
         logits, self.cache = self._exec("prefill", args)
         if adm.resume_tokens:
             # restore after preemption: the "next token" was sampled before
@@ -306,7 +321,8 @@ class PagedServingEngine:
             # token is discarded (greedy parity: it IS resume_tokens[-1])
             self._cur[slot] = adm.resume_tokens[-1]
             return False
-        tok = int(np.asarray(jnp.argmax(logits[0, 0])))
+        with tracing.span("serving.prefill.sample"):
+            tok = int(np.asarray(jnp.argmax(logits[0, 0])))
         self._cur[slot] = tok
         self.generated += 1
         if self.sched.record_prefill_token(slot, tok):
@@ -354,17 +370,32 @@ class PagedServingEngine:
         """One batched decode step over every slot (active or not).  Appends
         each active slot's pending token, samples the next, advances the
         scheduler.  Returns the slots that finished this step."""
+        with tracing.span("serving.decode") as sp:
+            return self._decode(sp.attrs)
+
+    def _decode(self, counters: dict) -> list[int]:
+        """The body of :meth:`decode_step`; sets the step's ``counters``:
+        slots ``active``, page-table width ``n_cols``, ``tokens_held`` in
+        the cache for the active slots and ``token_capacity``, the tokens
+        their pages and the pool's reservations for them could hold."""
         if self.faults is not None:
             self.faults.set_step(self.decode_steps)
         active = self.sched.active_slots()
-        active = self._grow_with_preemption(active)
+        with tracing.span("serving.decode.grow"):
+            active = self._grow_with_preemption(active)
         if not active:
             return []
-        width = max((len(self.sched.slot(i).pages) for i in active), default=1)
-        n_cols = min(_next_pow2(width), self.max_cols)
-        args = (jnp.asarray(self._cur[:, None]), self.cache,
-                jnp.asarray(self.page_table[:, :n_cols]),
-                jnp.asarray(self.kv_len))
+        pages = [len(self.sched.slot(i).pages) for i in active]
+        n_cols = min(_next_pow2(max(pages)), self.max_cols)
+        counters.update(
+            active=len(active), n_cols=n_cols,
+            tokens_held=int(self.kv_len[active].sum()),
+            token_capacity=self.page_size * (sum(pages)
+                                             + self.sched._reserved))
+        with tracing.span("serving.decode.inputs"):
+            args = (jnp.asarray(self._cur[:, None]), self.cache,
+                    jnp.asarray(self.page_table[:, :n_cols]),
+                    jnp.asarray(self.kv_len))
         try:
             logits, cache2 = self._exec("decode", args)
         except StepRetriesExhausted as e:
@@ -386,18 +417,21 @@ class PagedServingEngine:
             self._incident("dropped_tick", step=self.decode_steps)
             return []
         self.cache = cache2
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1)).astype(np.int32)
-        self.sched.tick()
-        self.decode_steps += 1
-        finished = []
-        for i in active:
-            done = self.sched.append_token(i, int(nxt[i]))
-            self.kv_len[i] += 1
-            self._cur[i] = nxt[i]
-            self.generated += 1
-            if done:
-                self._evict(i)
-                finished.append(i)
+        with tracing.span("serving.decode.sample"):
+            nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1)).astype(
+                np.int32)
+        with tracing.span("serving.decode.commit"):
+            self.sched.tick()
+            self.decode_steps += 1
+            finished = []
+            for i in active:
+                done = self.sched.append_token(i, int(nxt[i]))
+                self.kv_len[i] += 1
+                self._cur[i] = nxt[i]
+                self.generated += 1
+                if done:
+                    self._evict(i)
+                    finished.append(i)
         return finished
 
     # -- deadlines ------------------------------------------------------------

@@ -46,6 +46,8 @@ import dataclasses
 from collections import deque
 from typing import Optional
 
+from repro import tracing
+
 from .kv_cache import PageAllocator
 from .resilience import (
     POLICIES,
@@ -206,6 +208,12 @@ class ContinuousBatchingScheduler:
         ``reserved`` the growth pages are additionally reserved.  FIFO
         head-of-line blocking is deliberate: skipping a big request to admit
         later small ones starves it forever under steady load."""
+        with tracing.span("serving.admit") as sp:
+            out = self._admit()
+            sp.attrs["admitted"] = len(out)
+        return out
+
+    def _admit(self) -> list[Admission]:
         out = []
         for i in range(self.max_slots):
             if self.slots[i] is not None or not self.queue:

@@ -131,6 +131,15 @@ class TestMonitor:
             assert ev is not None
         assert m.should_evict
 
+    def test_caller_timed_steps(self):
+        # the launcher times each step once, as a span, and hands the time in
+        m = StepMonitor(window=50, threshold=2.0, patience=2)
+        for i in range(12):
+            assert m.end_step(i, 1.0) is None
+        assert m.end_step(12, 2.5).step_time == 2.5
+        assert m.end_step(13, 3.0) is not None
+        assert m.should_evict
+
     def test_heartbeat(self, tmp_path):
         hb = tmp_path / "hb.json"
         m = StepMonitor(heartbeat_path=str(hb))
