@@ -108,15 +108,15 @@ def _make_cell(key, B, capacity, valid, ps, hkv, g, dh):
             rows[r].extend(alloc.alloc(1))
     pt_live = jnp.asarray(np.asarray(rows, np.int32))
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(key), 3)
-    kp = jax.random.normal(k1, (hkv, pool, ps, dh), jnp.float32)
-    vp = jax.random.normal(k2, (hkv, pool, ps, dh), jnp.float32)
+    kp = jax.random.normal(k1, (1, hkv, pool, ps, dh), jnp.float32)
+    vp = jax.random.normal(k2, (1, hkv, pool, ps, dh), jnp.float32)
     q = jax.random.normal(k3, (B, 1, hkv * g, dh), jnp.float32)
     kv_len = jnp.full((B,), valid, jnp.int32)
     # dense capacity view: live tokens then zeros out to capacity
     k_dense = np.zeros((B, capacity, hkv, dh), np.float32)
     v_dense = np.zeros((B, capacity, hkv, dh), np.float32)
-    k_dense[:, :npg_live * ps] = np.asarray(gather_pages(kp, pt_live))
-    v_dense[:, :npg_live * ps] = np.asarray(gather_pages(vp, pt_live))
+    k_dense[:, :npg_live * ps] = np.asarray(gather_pages(kp[0], pt_live))
+    v_dense[:, :npg_live * ps] = np.asarray(gather_pages(vp[0], pt_live))
     return q, kp, vp, pt_live, kv_len, jnp.asarray(k_dense), jnp.asarray(v_dense)
 
 
@@ -146,7 +146,7 @@ def main(argv=None):
     table = sfu.get_store().get(fn="exp", n_breakpoints=args.breakpoints)
 
     split_fn = lambda q, kp, vp, pt, kvl: fused.paged_flash_decode(  # noqa: E731
-        q, kp, vp, pt, kvl, table=table)
+        q, kp, vp, pt, kvl, 0, table=table)
     flash_fn = jax.jit(lambda q, k, v, kvl: fused.fused_flash_attention(
         q, k, v, table=table, causal=False, kv_valid_len=kvl))
     dense_fn = jax.jit(_dense_decode)

@@ -9,8 +9,9 @@ the grid instead: each split produces softmax *partials* and a tiny merge
 combines them — same math, KV-parallel.
 
 This kernel additionally gathers K/V **through a page table**
-(``repro.serving.kv_cache`` layout: pools ``(Hkv, P, page_size, dh)``,
-table ``(B, n_pages)``), so it reads exactly the pages a request owns —
+(``repro.serving.kv_cache`` layout: the layers' stacked pools
+``(n_layers, Hkv, P, page_size, dh)`` and a layer index, table
+``(B, n_pages)``), so it reads exactly the pages a request owns —
 the grid is sized by the page table's *column count* (which the serving
 engine buckets to the live maximum), not by the logical cache capacity:
 a 500k-capacity cache holding 2k valid tokens does work proportional to
@@ -25,9 +26,11 @@ Structure:
 * grouped query heads fold into the *sublane* axis: the q tile per
   (request, kv-head) cell is ``(G, dh)`` padded to 8 sublanes, so GQA
   groups ride for free instead of multiplying the grid;
-* the K/V block index maps read the scalar-prefetched page table —
-  ``(h, page_table[b, split * pps + p], 0, 0)`` — so fragmented
-  (non-contiguous) page IDs cost nothing;
+* the K/V block index maps read the scalar-prefetched layer and page
+  table — ``(layer, h, page_table[b, split * pps + p], 0, 0)`` — so
+  fragmented (non-contiguous) page IDs cost nothing, and the pools are
+  DMA'd page by page at their own dtype (a bf16 page is cast to f32 in
+  VMEM, exactly): no copy of a layer's pool is made outside the kernel;
 * splits/pages past a request's valid length are skipped outright
   (no gather target is touched beyond the sentinel page, no matmul);
 * per split the kernel emits ``(m, l, acc)`` partials; the cross-split
@@ -69,8 +72,9 @@ from .softmax import _NEG_FILL, _SHIFT_CLAMP
 DEFAULT_SPLIT_KEYS = 2048
 
 
-def _decode_kernel(pt_ref, kvl_ref, *refs, plan, pps: int, ps: int,
-                   scale: float, hkv: int):
+def _decode_kernel(layer_ref, pt_ref, kvl_ref, *refs, plan, pps: int,
+                   ps: int, scale: float, hkv: int):
+    del layer_ref  # consumed by the index maps
     n_tab = plan.n_operands
     q_ref, k_ref, v_ref = refs[0], refs[1], refs[2]
     tab_refs = refs[3: 3 + n_tab]
@@ -94,7 +98,7 @@ def _decode_kernel(pt_ref, kvl_ref, *refs, plan, pps: int, ps: int,
     @pl.when(page0 < kvl)
     def _():
         q = q_ref[0]        # (Gp, dh)
-        k = k_ref[0, 0]     # (ps, dh)
+        k = k_ref[0, 0, 0].astype(jnp.float32)  # (ps, dh)
         sc = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -118,7 +122,8 @@ def _decode_kernel(pt_ref, kvl_ref, *refs, plan, pps: int, ps: int,
         )
         l_new = l_prev * corr + jnp.sum(pr, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            pr, v_ref[0, 0], preferred_element_type=jnp.float32
+            pr, v_ref[0, 0, 0].astype(jnp.float32),
+            preferred_element_type=jnp.float32,
         )
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -150,43 +155,42 @@ def merge_split_partials(m_p, l_p, acc_p, plan, tables):
 
 @functools.partial(jax.jit, static_argnames=(
     "plan", "g", "pps", "interpret"))
-def _paged_decode(q, k_pages, v_pages, page_table, kv_len, tables, *, plan,
-                  g, pps, interpret):
-    """q: (B*Hkv, Gp, dh) f32;  pools: (Hkv, P, ps, dh);
-    page_table: (B, n_cols) i32 padded to a multiple of pps;
-    kv_len: (B,) i32.  Returns (B*Hkv, Gp, dh) f32."""
+def _paged_decode(q, k_pages, v_pages, layer, page_table, kv_len, tables, *,
+                  plan, g, pps, interpret):
+    """q: (B*Hkv, Gp, dh) f32;  pools: (n_layers, Hkv, P, ps, dh) at their
+    own dtype;  layer: (1,) i32;  page_table: (B, n_cols) i32 padded to a
+    multiple of pps;  kv_len: (B,) i32.  Returns (B*Hkv, Gp, dh) f32."""
     A, gp, dh = q.shape
-    Hkv, P, ps, _ = k_pages.shape
+    _, Hkv, P, ps, _ = k_pages.shape
     n_splits = page_table.shape[1] // pps
     grid = (A, n_splits, pps)
     scale = 1.0 / math.sqrt(dh)
 
+    def page_map(a, s, p, ly, pt, kvl):
+        return (ly[0], a % Hkv, pt[a // Hkv, s * pps + p], 0, 0)
+
+    def split_map(a, s, p, ly, pt, kvl):
+        return (a, s, 0, 0)
+
+    page_spec = pl.BlockSpec((1, 1, 1, ps, dh), page_map)
     in_specs = [
-        pl.BlockSpec((1, gp, dh), lambda a, s, p, pt, kvl: (a, 0, 0)),
-        pl.BlockSpec(
-            (1, 1, ps, dh),
-            lambda a, s, p, pt, kvl, _h=Hkv, _pps=pps:
-                (a % _h, pt[a // _h, s * _pps + p], 0, 0),
-        ),
-        pl.BlockSpec(
-            (1, 1, ps, dh),
-            lambda a, s, p, pt, kvl, _h=Hkv, _pps=pps:
-                (a % _h, pt[a // _h, s * _pps + p], 0, 0),
-        ),
+        pl.BlockSpec((1, gp, dh), lambda a, s, p, ly, pt, kvl: (a, 0, 0)),
+        page_spec,
+        page_spec,
     ]
     for rows, cols in plan.table_specs():
         in_specs.append(
-            pl.BlockSpec((rows, cols), lambda a, s, p, pt, kvl: (0, 0))
+            pl.BlockSpec((rows, cols), lambda a, s, p, ly, pt, kvl: (0, 0))
         )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, gp, 128), lambda a, s, p, pt, kvl: (a, s, 0, 0)),
-            pl.BlockSpec((1, 1, gp, 128), lambda a, s, p, pt, kvl: (a, s, 0, 0)),
-            pl.BlockSpec((1, 1, gp, dh), lambda a, s, p, pt, kvl: (a, s, 0, 0)),
+            pl.BlockSpec((1, 1, gp, 128), split_map),
+            pl.BlockSpec((1, 1, gp, 128), split_map),
+            pl.BlockSpec((1, 1, gp, dh), split_map),
         ],
         scratch_shapes=[
             pltpu.VMEM((gp, 128), jnp.float32),  # running row max
@@ -204,24 +208,26 @@ def _paged_decode(q, k_pages, v_pages, page_table, kv_len, tables, *, plan,
             jax.ShapeDtypeStruct((A, n_splits, gp, dh), jnp.float32),
         ],
         interpret=interpret,
-    )(page_table, kv_len, q, k_pages, v_pages, *tables)
+    )(layer, page_table, kv_len, q, k_pages, v_pages, *tables)
     # (A, ns, Gp, 128) -> (A, ns, Gp): partials are lane-broadcast
     return merge_split_partials(m_p[..., 0], l_p[..., 0], acc_p, plan, tables)
 
 
 def paged_flash_decode(
     q: jax.Array,           # (B, 1, H, dh) — single-token decode queries
-    k_pages: jax.Array,     # (Hkv, P, page_size, dh)
-    v_pages: jax.Array,     # (Hkv, P, page_size, dh)
+    k_pages: jax.Array,     # (n_layers, Hkv, P, page_size, dh)
+    v_pages: jax.Array,     # (n_layers, Hkv, P, page_size, dh)
     page_table: jax.Array,  # (B, n_pages) int32 (0 = sentinel/unallocated)
     kv_len: jax.Array,      # (B,) int32 valid prefix length (0 = inactive)
+    layer,                  # int or int32 scalar: which layer's pool
     *,
     table: PWLTable | None = None,
     act: str | None = None,
     pages_per_split: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Split-KV flash decoding through a page table (see module docstring).
+    """Split-KV flash decoding through a page table (see module docstring),
+    over layer ``layer`` of the stacked pools, read at their own dtype.
 
     table: PWL exp table (the ``attn.softmax:exp`` site); ``act="exp"``
     (default when neither is given) runs the exact exponential through the
@@ -238,7 +244,7 @@ def paged_flash_decode(
     B, S, H, dh = q.shape
     if S != 1:
         raise ValueError(f"paged_flash_decode takes single-token queries, got S={S}")
-    Hkv, P, ps, _ = k_pages.shape
+    _, Hkv, P, ps, _ = k_pages.shape
     G = H // Hkv
     gp = _round_up(G, 8)
     pps = pages_per_split or max(1, DEFAULT_SPLIT_KEYS // ps)
@@ -256,7 +262,7 @@ def paged_flash_decode(
     qf = jnp.pad(qf, ((0, 0), (0, gp - G), (0, 0)))
 
     out = _paged_decode(
-        qf, k_pages.astype(jnp.float32), v_pages.astype(jnp.float32), pt,
+        qf, k_pages, v_pages, jnp.asarray(layer, jnp.int32).reshape(1), pt,
         kv_len.astype(jnp.int32), tables, plan=plan, g=G, pps=pps,
         interpret=interpret,
     )

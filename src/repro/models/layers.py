@@ -508,10 +508,11 @@ def decode_attention(
 
 def paged_decode_attention(
     q,           # (B, 1, H, dh)
-    k_pages,     # (Hkv, P, page_size, dh)
-    v_pages,     # (Hkv, P, page_size, dh)
+    k_pages,     # (n_layers, Hkv, P, page_size, dh) — every layer's pool
+    v_pages,     # (n_layers, Hkv, P, page_size, dh)
     page_table,  # (B, n_pages) int32
     kv_len,      # (B,) int32 — tokens to attend (incl. the one just written)
+    layer,       # int or int32 scalar — the pool this layer attends
     exp_fn: Callable = jnp.exp,
     softmax_table=None,
 ):
@@ -521,10 +522,11 @@ def paged_decode_attention(
     ``impl="fused"``), the split-KV flash-decoding kernel gathers K/V
     through the page table inside the kernel — no dense cache is ever
     materialized, and work scales with the table's column count, not the
-    pool capacity.  Otherwise (exact/jnp/kernel plans) the pages are
-    gathered into logical order once and :func:`decode_attention` runs its
-    elementwise formulation — the unfused fallback docs/distributed.md
-    documents.
+    pool capacity; it reads ``layer``'s pool in place, at its own dtype.
+    Otherwise (exact/jnp/kernel plans) the layer's pool is sliced out and
+    its pages gathered into logical order once, and :func:`decode_attention`
+    runs its elementwise formulation — the unfused fallback
+    docs/distributed.md documents.
 
     Under a multi-device mesh the split-KV kernel runs per-shard: the page
     pools shard over KV heads (each rank owns whole pools for its head
@@ -540,7 +542,8 @@ def paged_decode_attention(
         rules = active_mesh_rules()
         if rules is None:
             return sfu.guard.check_fused(softmax_key, fused.paged_flash_decode(
-                q, k_pages, v_pages, page_table, kv_len, table=softmax_table
+                q, k_pages, v_pages, page_table, kv_len, layer,
+                table=softmax_table,
             ))
         if logical_extent(rules, "cache_seq") > 1:
             sfu.warn_fused_fallback(
@@ -552,26 +555,29 @@ def paged_decode_attention(
             )
         else:
             B, _, H, _ = q.shape
-            Hkv = k_pages.shape[0]
+            Hkv = k_pages.shape[1]
             b = shf.batch_entry(rules, B)
             h, hk = _gqa_shard_entries(rules, "act_heads", H, "cache_kv", Hkv)
+            pool = shf.P(None, hk, None, None, None)
             table = softmax_table
 
-            def body(q_l, kp_l, vp_l, pt_l, len_l):
+            def body(q_l, kp_l, vp_l, pt_l, len_l, ly):
                 return fused.paged_flash_decode(
-                    q_l, kp_l, vp_l, pt_l, len_l, table=table
+                    q_l, kp_l, vp_l, pt_l, len_l, ly, table=table
                 )
 
             return sfu.guard.check_fused(softmax_key, shf.run_sharded(
-                rules, body, (q, k_pages, v_pages, page_table, kv_len),
-                (shf.P(b, None, h, None), shf.P(hk, None, None, None),
-                 shf.P(hk, None, None, None), shf.P(b, None), shf.P(b)),
+                rules, body,
+                (q, k_pages, v_pages, page_table, kv_len,
+                 jnp.asarray(layer, jnp.int32)),
+                (shf.P(b, None, h, None), pool, pool, shf.P(b, None),
+                 shf.P(b), shf.P()),
                 shf.P(b, None, h, None),
             ))
     from repro.serving.kv_cache import gather_pages
 
-    k_dense = gather_pages(k_pages, page_table)
-    v_dense = gather_pages(v_pages, page_table)
+    k_dense = gather_pages(k_pages[layer], page_table)
+    v_dense = gather_pages(v_pages[layer], page_table)
     T = k_dense.shape[1]
     valid = jnp.arange(T)[None, :] < kv_len[:, None]
     return decode_attention(q, k_dense, v_dense, valid, exp_fn)
@@ -899,8 +905,9 @@ def attention_layer(
     cross_kv=None,             # (k, v) for cross-attention (whisper)
     use_rope: bool = True,
     plan=None,                 # repro.sfu.ActivationPlan (softmax-exp site)
-    paged=None,                # dict(page_table, kv_len) — serving's paged
-    #                            KV cache (cache holds k_pages/v_pages)
+    paged=None,                # dict(page_table, kv_len, layer) — serving's
+    #                            paged KV cache (cache holds every layer's
+    #                            stacked k_pages/v_pages; `layer` picks one)
 ):
     """Returns (y, new_cache).  Train/prefill when cache is None or a fresh
     buffer being filled; decode when x has seq_len 1 and cache is given."""
@@ -940,9 +947,11 @@ def attention_layer(
     if cache is not None and "k_pages" in cache:
         # paged KV cache (repro.serving): k/v live in a shared page pool,
         # the per-request page table maps logical position -> physical slot.
+        # The pools arrive stacked over layers and are written in place at
+        # `layer`, so the scan carries them without slicing or restacking.
         from repro.serving import kv_cache as _pg
 
-        page_table = paged["page_table"]
+        page_table, layer = paged["page_table"], paged["layer"]
         if S == 1:
             # decode: in-place append at kv_len, then attend the kv_len+1
             # prefix through the page table (split-KV kernel when the
@@ -952,11 +961,12 @@ def attention_layer(
             # finite and discarded by the scheduler.
             kv_len = paged["kv_len"]
             k_pages, v_pages = _pg.append_kv(
-                cache["k_pages"], cache["v_pages"], k, v, page_table, kv_len
+                cache["k_pages"], cache["v_pages"], k, v, page_table, kv_len,
+                layer,
             )
             new_cache = {"k_pages": k_pages, "v_pages": v_pages}
             y = paged_decode_attention(
-                q, k_pages, v_pages, page_table, kv_len + 1, exp_fn,
+                q, k_pages, v_pages, page_table, kv_len + 1, layer, exp_fn,
                 softmax_table=_softmax_fused_table(plan),
             )
         else:
@@ -964,7 +974,7 @@ def attention_layer(
             # pages — the engine buckets prompts to a page multiple) and
             # attend causally over the in-flight k/v, never via the pool.
             k_pages, v_pages = _pg.write_prompt_pages(
-                cache["k_pages"], cache["v_pages"], k, v, page_table
+                cache["k_pages"], cache["v_pages"], k, v, page_table, layer
             )
             new_cache = {"k_pages": k_pages, "v_pages": v_pages}
             y = _attn_softmax_dispatch(

@@ -161,8 +161,8 @@ def block_apply(cfg: ModelConfig, p, h, mixer: str, ffn: str, cache=None,
 
     ``plan`` is the compiled activation plan threaded down from the forward
     entry points (one ``sfu.plan_for`` per trace, not per layer);
-    ``paged`` is the serving path's shared {page_table, kv_len} (the
-    per-layer page pools ride in ``cache``)."""
+    ``paged`` is the serving path's shared {page_table, kv_len} plus this
+    layer's index into the stacked page pools, which ride in ``cache``."""
     plan = plan if plan is not None else sfu.plan_for(cfg)
     hn = L.apply_norm(cfg, p["ln1"], h)
     if mixer == "ssm":
@@ -320,12 +320,12 @@ def make_cache(cfg: ModelConfig, batch: int, max_len: int):
 
 
 def _scan_with_cache(cfg: ModelConfig, params, h, cache, pos, paged=None):
+    if "k_pages" in cache[0]:
+        return _scan_with_pools(cfg, params, h, cache, pos, paged)
     kinds = cfg.layer_kinds
     period = cfg.period
     plan = sfu.plan_for(cfg)
 
-    # `paged` (page_table + kv_len) is shared by every layer, so it enters
-    # the scan body as a closure constant, not a scanned xs leaf.
     # sfu.guard counters emitted inside the scan body would leak inner-trace
     # tracers into the engine's collector, so the body reroutes them through
     # guard.capture() and threads them out as scan ys; guard.emit sums the
@@ -337,7 +337,7 @@ def _scan_with_cache(cfg: ModelConfig, params, h, cache, pos, paged=None):
             for j in range(period):
                 h, nc, _ = block_apply(
                     cfg, stacked[j], h, *kinds[j], cache=cache_p[j], pos=pos,
-                    plan=plan, paged=paged,
+                    plan=plan,
                 )
                 new_caches.append(nc)
         return h, (new_caches, cap.result())
@@ -357,6 +357,46 @@ def _scan_with_cache(cfg: ModelConfig, params, h, cache, pos, paged=None):
         outs.append(nc)
     new_cache = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
     return h, new_cache
+
+
+def _scan_with_pools(cfg: ModelConfig, params, h, pools, pos, paged):
+    """The layer scan over the paged cache: the stacked pools ride the carry
+    and each layer writes and reads its own slot of them in place, through
+    the period index.  A dense per-layer cache is small and rewritten, so it
+    rides xs/ys (:func:`_scan_with_cache`); a shared pool is large and gains
+    one token a step, and slicing it out and stacking it back would copy
+    every layer's pool twice per step."""
+    kinds = cfg.layer_kinds
+    period = cfg.period
+    plan = sfu.plan_for(cfg)
+
+    # `paged` (page_table + kv_len) is shared by every layer, so it enters
+    # the scan body as a closure constant; guard counters leave as ys, as in
+    # _scan_with_cache.
+    def period_fn(carry, stacked, i):
+        h, pools = carry
+        pools = list(pools)
+        with sfu.guard.capture() as cap:
+            for j in range(period):
+                h, pools[j], _ = block_apply(
+                    cfg, stacked[j], h, *kinds[j], cache=pools[j], pos=pos,
+                    plan=plan, paged={**paged, "layer": i},
+                )
+        return (h, pools), cap.result()
+
+    n_periods = cfg.n_layers // period
+    if cfg.scan_layers:
+        (h, pools), gcounts = jax.lax.scan(
+            lambda carry, xs: period_fn(carry, *xs), (h, pools),
+            (params["layers"], jnp.arange(n_periods, dtype=jnp.int32)),
+        )
+        sfu.guard.emit(gcounts)
+        return h, pools
+    for i in range(n_periods):
+        stacked = jax.tree_util.tree_map(lambda x: x[i], params["layers"])
+        (h, pools), gcounts = period_fn((h, pools), stacked, i)
+        sfu.guard.emit(gcounts)
+    return h, pools
 
 
 def prefill(cfg: ModelConfig, params, tokens, cache, vision_embeds=None):

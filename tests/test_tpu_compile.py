@@ -84,17 +84,21 @@ def _table(fn):
 @pytest.mark.parametrize("page_size", [16, 128])
 @pytest.mark.parametrize("kernel", ["append_kv", "write_prompt_pages"])
 def test_pool_write_kernels_compile_bf16(one_chip, mosaic, kernel, page_size):
-    hkv, pages, dh, batch = 16, 17, 128, 4
-    pool = _struct(one_chip, (hkv, pages, page_size, dh), jnp.bfloat16)
+    # the layers' stacked pools, written at a traced layer index
+    layers, hkv, pages, dh, batch = 4, 16, 17, 128, 4
+    pool = _struct(one_chip, (layers, hkv, pages, page_size, dh),
+                   jnp.bfloat16)
+    layer = _struct(one_chip, (), jnp.int32)
     if kernel == "append_kv":
         new = _struct(one_chip, (batch, 1, hkv, dh), jnp.bfloat16)
         args = (pool, pool, new, new,
                 _struct(one_chip, (batch, 4), jnp.int32),
-                _struct(one_chip, (batch,), jnp.int32))
+                _struct(one_chip, (batch,), jnp.int32), layer)
         text = _compiled_text(kv_cache.append_kv, *args)
     else:
         new = _struct(one_chip, (1, 2 * page_size, hkv, dh), jnp.bfloat16)
-        args = (pool, pool, new, new, _struct(one_chip, (1, 4), jnp.int32))
+        args = (pool, pool, new, new, _struct(one_chip, (1, 4), jnp.int32),
+                layer)
         text = _compiled_text(kv_cache.write_prompt_pages, *args)
     assert "tpu_custom_call" in text
 
@@ -111,6 +115,10 @@ def olmo_1b():
 
 @pytest.mark.parametrize("step", ["prefill_paged", "decode_step_paged"])
 def test_olmo_1b_paged_step_compiles(one_chip, mosaic, olmo_1b, step):
+    """Both steps compile through Mosaic with the layer-indexed pool kernels
+    on bf16 pages of 128; neither slices a layer's pool out of the stack
+    nor casts one to f32 (the decode kernel reads pages at their own
+    dtype)."""
     slots, page_size, pages, prompt = 4, 128, 17, 256
     params = _tree_structs(one_chip, olmo_1b.param_structs())
     cache = _tree_structs(
@@ -127,6 +135,14 @@ def test_olmo_1b_paged_step_compiles(one_chip, mosaic, olmo_1b, step):
                 _struct(one_chip, (slots,), i32))
     text = _compiled_text(getattr(olmo_1b, step), params, *args)
     assert "tpu_custom_call" in text
+    stack = cache[0]["k_pages"]
+    assert stack.dtype == jnp.bfloat16
+    layer_pool = ",".join(map(str, stack.shape[1:]))
+    assert f"f32[{layer_pool}]" not in text
+    assert f"bf16[{layer_pool}]" not in text
+    assert f"f32[{stack.shape[0]},{layer_pool}]" not in text
+    if step == "decode_step_paged":
+        assert "%_paged_decode" in text
 
 
 # --------------------------------------------------------------------------
