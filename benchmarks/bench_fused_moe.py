@@ -1,14 +1,14 @@
 """Fused-vs-unfused MoE expert FFN latency: exact / jnp / fused.
 
-The MoE sibling of ``bench_fused_mlp.py`` (ISSUE 4): after token dispatch,
-every expert applies its own GLU to a (capacity, d_model) bucket —
+The MoE sibling of ``bench_fused_mlp.py``: the rows routed to the experts,
+grouped by expert (``--capacity`` rows each), go through each expert's GLU —
 
-    h = act(buf @ Wg[e]) * (buf @ Wu[e]);   y = h @ Wd[e]
+    h_r = act(x_r @ Wg[e]) * (x_r @ Wu[e]);   y_r = h_r @ Wd[e]
 
-Unfused, the two (E, C, F) pre-activations and the activation output each
-round-trip HBM; ``fused`` evaluates the non-uniform PWL decode as an
-epilogue of the per-expert gemms (kernels/fused/moe.py) so the activation
-and gating cost zero extra traffic.  Emits CSV rows via benchmarks/common.py
+Unfused (``jax.lax.ragged_dot``), the two (rows, F) pre-activations and the
+activation output each round-trip HBM; ``fused`` evaluates the non-uniform
+PWL decode as an epilogue of the grouped gemms (kernels/fused/moe.py) so the
+activation and gating cost zero extra traffic.  Emits CSV rows via benchmarks/common.py
 AND a machine-readable ``BENCH_fused_moe.json`` (per-mode latency + output
 MSE vs the exact mode) at the repo root.
 
@@ -38,7 +38,7 @@ except ImportError:
     from common import emit, provenance, time_fn, write_bench_json
 
 
-def make_expert_ffn(mode: str, table):
+def make_expert_ffn(mode: str, table, sizes, row_tile: int):
     if mode == "exact":
         from repro.core import functions as F
 
@@ -50,14 +50,15 @@ def make_expert_ffn(mode: str, table):
     if mode == "fused":
         @jax.jit
         def ffn(x, wg, wu, wd):
-            h = fused.fused_moe_glu(x, wg, wu, table=table)
-            return jnp.einsum("ecf,efd->ecd", h, wd)
+            h = fused.fused_moe_glu(x, wg, wu, sizes, row_tile=row_tile,
+                                    table=table)
+            return fused.moe.moe_down(h, wd, sizes, row_tile=row_tile)
     else:
         @jax.jit
         def ffn(x, wg, wu, wd):
-            g = jnp.einsum("ecd,edf->ecf", x, wg)
-            u = jnp.einsum("ecd,edf->ecf", x, wu)
-            return jnp.einsum("ecf,efd->ecd", act(g) * u, wd)
+            g = jax.lax.ragged_dot(x, wg, sizes)
+            u = jax.lax.ragged_dot(x, wu, sizes)
+            return jax.lax.ragged_dot(act(g) * u, wd, sizes)
 
     return ffn
 
@@ -88,8 +89,11 @@ def main(argv=None):
     )
     kx, kg, ku, kd = jax.random.split(jax.random.PRNGKey(0), 4)
     dtype = jnp.float32 if jax.default_backend() == "cpu" else jnp.bfloat16
-    E, C, D, F = args.experts, args.capacity, args.d_model, args.d_ff
-    x = jax.random.normal(kx, (E, C, D), dtype)
+    E, D, F = args.experts, args.d_model, args.d_ff
+    row_tile = fused.moe.row_tile(args.capacity)
+    C = -(-args.capacity // row_tile) * row_tile
+    sizes = jnp.full((E,), C, jnp.int32)
+    x = jax.random.normal(kx, (E * C, D), dtype)
     wg = jax.random.normal(kg, (E, D, F), dtype) * 0.02
     wu = jax.random.normal(ku, (E, D, F), dtype) * 0.02
     wd = jax.random.normal(kd, (E, F, D), dtype) * 0.02
@@ -100,7 +104,7 @@ def main(argv=None):
     y_exact = None
     results = {}
     for mode in ("exact", "jnp", "fused"):
-        fn = make_expert_ffn(mode, table)
+        fn = make_expert_ffn(mode, table, sizes, row_tile)
         us = time_fn(fn, x, wg, wu, wd,
                      warmup=1 if args.quick else 2, iters=iters)
         y = fn(x, wg, wu, wd).astype(jnp.float32)

@@ -1,5 +1,6 @@
 """olmoe-1b-7b [moe]: 16L d_model=2048 16H (GQA kv=16) d_ff=1024(per expert)
-vocab=50304, MoE 64 experts top-8 [arXiv:2409.02060; hf]."""
+vocab=50304, MoE 64 experts top-8, QK-norm, top-8 weights not renormalised,
+untied head, RMSNorm eps 1e-5 [arXiv:2409.02060; allenai/OLMoE-1B-7B-0924]."""
 import dataclasses
 
 from repro.models import ModelConfig
@@ -19,6 +20,9 @@ CONFIG = ModelConfig(
     activation="silu",
     mlp_type="swiglu",
     norm_type="rmsnorm",
+    norm_eps=1e-5,
+    qk_norm=True,
+    norm_topk=False,
 )
 
 

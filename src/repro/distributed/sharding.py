@@ -196,7 +196,6 @@ def make_rules(
         "mlp": "model",
         "vocab": "model" if shard_vocab else None,
         "experts": "model",
-        "capacity": "data",  # MoE dispatch buffers: capacity rows over data
         "flat_tokens": batch_axes,
         "layers": None,
         "state": None,

@@ -1,25 +1,25 @@
-"""Fused MoE-expert GLU Pallas kernel: ``act(x[e] @ Wg[e]) * (x[e] @ Wu[e])``.
+"""Grouped MoE-expert kernels over rows sorted by expert.
 
-The MoE expert FFN (``models/moe.py``) runs a *batched* GLU: after dispatch,
-every expert owns a ``(capacity, d_model)`` bucket of tokens and applies its
-own gate/up projections.  Unfused, the two ``ecd,edf->ecf`` einsums each
-write a full ``(E, C, F)`` pre-activation to HBM, the activation reads one
-back, and the gating multiply reads both — exactly the round-trip the paper
-removes (Sec. V: the SFU evaluates the nonlinearity beside the MAC array).
+The MoE layer (``models/moe.py``) routes every (token, choice) row to one
+expert and drops none.  It lays the rows out grouped by expert, each group
+padded with zero rows to a multiple of the row tile ``bm``, so that every
+row tile belongs to one expert.  Two kernels then run over that layout:
 
-Here the expert dim is the *outer grid axis*: for each expert the kernel is
-the same two-accumulator blocked GLU as ``fused/glu.py`` — both gemms share
-the x tile, accumulate in two f32 VMEM scratch tiles, and on the last k step
-the non-uniform PWL decode (``fused/epilogue.pwl_eval_tile``) evaluates on
-the gate accumulator and multiplies with the up accumulator before the single
-writeback.  Per-expert weights arrive as ``(1, bk, bn)`` blocks indexed by
-the expert grid coordinate, so no expert ever materializes another expert's
-tiles.
+* :func:`fused_moe_glu`, ``act(x_r @ Wg[e]) * (x_r @ Wu[e])`` for each row
+  ``r`` of expert ``e``.  Both gemms share the row tile, and the non-uniform
+  PWL decode (``fused/epilogue.pwl_eval_tile``) evaluates on the gate
+  product and multiplies with the up product before the single writeback,
+  so the ``(rows, F)`` pre-activations never round-trip HBM (the paper's
+  Sec. V: the SFU evaluates the nonlinearity beside the MAC array).
+* :func:`moe_down`, the grouped down projection ``h_r @ Wd[e]``.
 
-Grid ``(E, C/bm, F/bn, K/bk)`` with k innermost: TPU grids iterate
-minor-to-major sequentially, so the accumulator scratch is valid across k
-steps for each (e, i, j) tile.  Padding follows ``fused/linear.py`` (zeros
-contribute nothing to the accumulator; padded rows/cols are sliced away).
+The group sizes arrive as scalar prefetch, turned into one expert index per
+row tile: a row tile's weight blocks are its own expert's, and an expert
+that took no row is never read.  The grid is ``(N/bn, row tiles)`` with the
+row tiles innermost and the whole contraction in one block, so consecutive
+tiles of one expert keep the same weight block and the pipeline fetches it
+once.  Tiles past the last group (the layout is sized for the most rows a
+call can take) read nothing new and write zeros.
 """
 from __future__ import annotations
 
@@ -35,196 +35,265 @@ from repro.core.pwl import PWLTable
 from .._backend import should_interpret
 from .backward import resolve_impl_bwd
 from .epilogue import EpiloguePlan, plan_and_operands, plan_value_and_slope
-from .linear import DEFAULT_BLOCK, _aligned_block, _pad_to
+
+BLOCK_N = 512
 
 
-def _moe_glu_kernel(*refs, plan: EpiloguePlan, nk: int):
-    n_tab = plan.n_operands
-    x_ref, wg_ref, wu_ref = refs[0], refs[1], refs[2]
-    tab_refs = refs[3 : 3 + n_tab]
-    o_ref, accg_ref, accu_ref = refs[3 + n_tab], refs[4 + n_tab], refs[5 + n_tab]
+def row_tile(rows_per_group: float) -> int:
+    """The row tile for groups of about ``rows_per_group`` rows: a power of
+    two from 16 (the bf16 sublane tile) to 128 (the MXU), at least half a
+    group, so that padding costs at most about half a tile per group."""
+    bm = 16
+    while bm < 128 and bm < rows_per_group / 2:
+        bm *= 2
+    return bm
 
-    k = pl.program_id(3)
 
-    @pl.when(k == 0)
+def tile_experts(group_sizes, n_tiles: int, bm: int):
+    """The expert of each of ``n_tiles`` row tiles, and how many tiles hold
+    rows, for groups of ``group_sizes`` rows (each a multiple of ``bm``)
+    laid out in order.  Tiles past the last group keep the last group's
+    expert, so the pipeline fetches no new weights for them."""
+    ends = jnp.cumsum(group_sizes // bm)
+    n_used = ends[-1]
+    t = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32),
+                    jnp.maximum(n_used - 1, 0))
+    # the groups that end at or before a tile come before its own
+    te = jnp.sum(ends[None, :] <= t[:, None], axis=1, dtype=jnp.int32)
+    te = jnp.minimum(te, group_sizes.shape[0] - 1)
+    return te, n_used.reshape(1).astype(jnp.int32)
+
+
+def _block_n(n: int) -> int:
+    return BLOCK_N if n % BLOCK_N == 0 else n
+
+
+def _grid_spec(n_in_w: int, n_tab_specs, m: int, k: int, n: int, bm: int,
+               bn: int, extra_row_inputs: int = 0, n_out: int = 1):
+    def rows(j, t, te, nu):
+        return (jnp.minimum(t, jnp.maximum(nu[0] - 1, 0)), 0)
+
+    def rows_j(j, t, te, nu):
+        return (jnp.minimum(t, jnp.maximum(nu[0] - 1, 0)), j)
+
+    in_specs = [pl.BlockSpec((bm, k), rows)]
+    in_specs += [pl.BlockSpec((1, k, bn), lambda j, t, te, nu: (te[t], 0, j))
+                 ] * n_in_w
+    in_specs += [pl.BlockSpec((bm, bn), rows_j)] * extra_row_inputs
+    in_specs += [pl.BlockSpec(shape, lambda j, t, te, nu: (0, 0))
+                 for shape in n_tab_specs]
+    out = pl.BlockSpec((bm, bn), lambda j, t, te, nu: (t, j))
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(n // bn, m // bm), in_specs=in_specs,
+        out_specs=out if n_out == 1 else [out] * n_out)
+
+
+def _when_used(nu_ref, compute, outs):
+    t = pl.program_id(1)
+
+    @pl.when(t < nu_ref[0])
     def _():
-        accg_ref[...] = jnp.zeros_like(accg_ref)
-        accu_ref[...] = jnp.zeros_like(accu_ref)
+        compute()
 
-    x = x_ref[0]  # (bm, bk) tile of this expert's capacity bucket
-    accg_ref[...] += jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-    accu_ref[...] += jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-
-    @pl.when(k == nk - 1)
+    @pl.when(t >= nu_ref[0])
     def _():
-        g = plan.apply(accg_ref[...], *tab_refs)
-        o_ref[0] = (g * accu_ref[...]).astype(o_ref.dtype)
+        for o in outs:
+            o[...] = jnp.zeros_like(o)
 
 
-@functools.partial(jax.jit, static_argnames=("plan", "block", "interpret"))
-def _fused_moe_glu_3d(x, wg, wu, tables, *, plan, block, interpret):
-    E, C, K = x.shape
-    N = wg.shape[2]
-    bm, bn, bk = _aligned_block(block, (C, N, K), x.dtype)
-    xp = _pad_to(x, (1, bm, bk))
-    wgp = _pad_to(wg, (1, bk, bn))
-    wup = _pad_to(wu, (1, bk, bn))
-    Cp, Kp = xp.shape[1], xp.shape[2]
-    Np = wgp.shape[2]
-    nk = Kp // bk
-    grid = (E, Cp // bm, Np // bn, nk)
+def _glu_kernel(te_ref, nu_ref, x_ref, wg_ref, wu_ref, *rest,
+                plan: EpiloguePlan):
+    tab_refs, o_ref = rest[:plan.n_operands], rest[plan.n_operands]
 
-    in_specs = [
-        pl.BlockSpec((1, bm, bk), lambda e, i, j, k: (e, i, k)),
-        pl.BlockSpec((1, bk, bn), lambda e, i, j, k: (e, k, j)),
-        pl.BlockSpec((1, bk, bn), lambda e, i, j, k: (e, k, j)),
-    ]
-    for rows, cols in plan.table_specs():
-        in_specs.append(pl.BlockSpec((rows, cols), lambda e, i, j, k: (0, 0)))
+    def compute():
+        x = x_ref[...]
+        g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        o_ref[...] = (plan.apply(g, *tab_refs) * u).astype(o_ref.dtype)
 
-    out = pl.pallas_call(
-        functools.partial(_moe_glu_kernel, plan=plan, nk=nk),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bm, bn), lambda e, i, j, k: (e, i, j)),
-        out_shape=jax.ShapeDtypeStruct((E, Cp, Np), x.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bm, bn), jnp.float32),
-            pltpu.VMEM((bm, bn), jnp.float32),
-        ],
-        interpret=interpret,
-    )(xp, wgp, wup, *tables)
-    return out[:, :C, :N]
+    _when_used(nu_ref, compute, [o_ref])
 
 
-# --- autodiff: fused forward, fused (or jnp-recompute) backward ------------
-# (see fused/linear.py for the rationale; the backward kernel is the batched
-# analogue of fused/glu.py's — expert dim as the outer grid axis, two
-# accumulators recomputed blockwise, (dzg, dzu) emitted from one
-# value-and-slope decode; dx/dwg/dwu stay plain XLA einsums)
+def _glu_dz_kernel(te_ref, nu_ref, x_ref, wg_ref, wu_ref, g_ref, *rest,
+                   plan: EpiloguePlan):
+    tab_refs = rest[:plan.n_operands]
+    dzg_ref, dzu_ref = rest[plan.n_operands], rest[plan.n_operands + 1]
+
+    def compute():
+        x = x_ref[...]
+        zg = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+        zu = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        act_zg, slope = plan.apply_value_and_slope(zg, *tab_refs)
+        gf = g_ref[...].astype(jnp.float32)
+        dzg_ref[...] = gf * zu * slope
+        dzu_ref[...] = gf * act_zg
+
+    _when_used(nu_ref, compute, [dzg_ref, dzu_ref])
 
 
-def _moe_bwd_kernel(*refs, plan: EpiloguePlan, nk: int):
-    n_tab = plan.n_operands
-    x_ref, wg_ref, wu_ref, g_ref = refs[0], refs[1], refs[2], refs[3]
-    tab_refs = refs[4 : 4 + n_tab]
-    dzg_ref, dzu_ref = refs[4 + n_tab], refs[5 + n_tab]
-    accg_ref, accu_ref = refs[6 + n_tab], refs[7 + n_tab]
+def _down_kernel(te_ref, nu_ref, x_ref, w_ref, o_ref):
+    def compute():
+        o_ref[...] = jnp.dot(x_ref[...], w_ref[0],
+                             preferred_element_type=jnp.float32
+                             ).astype(o_ref.dtype)
 
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _():
-        accg_ref[...] = jnp.zeros_like(accg_ref)
-        accu_ref[...] = jnp.zeros_like(accu_ref)
-
-    x = x_ref[0]
-    accg_ref[...] += jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-    accu_ref[...] += jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-
-    @pl.when(k == nk - 1)
-    def _():
-        act_zg, slope = plan.apply_value_and_slope(accg_ref[...], *tab_refs)
-        gf = g_ref[0].astype(jnp.float32)
-        dzg_ref[0] = gf * accu_ref[...] * slope
-        dzu_ref[0] = gf * act_zg
+    _when_used(nu_ref, compute, [o_ref])
 
 
-@functools.partial(jax.jit, static_argnames=("plan", "block", "interpret"))
-def _moe_dz_3d(x, wg, wu, g, tables, *, plan, block, interpret):
-    """(dzg, dzu) of the per-expert GLU in one pass; each (E, C, N) f32."""
-    E, C, K = x.shape
-    N = wg.shape[2]
-    bm, bn, bk = _aligned_block(block, (C, N, K), x.dtype)
-    xp = _pad_to(x, (1, bm, bk))
-    wgp = _pad_to(wg, (1, bk, bn))
-    wup = _pad_to(wu, (1, bk, bn))
-    gp = _pad_to(g.astype(jnp.float32), (1, bm, bn))
-    Cp, Kp = xp.shape[1], xp.shape[2]
-    Np = wgp.shape[2]
-    nk = Kp // bk
-    grid = (E, Cp // bm, Np // bn, nk)
-
-    in_specs = [
-        pl.BlockSpec((1, bm, bk), lambda e, i, j, k: (e, i, k)),
-        pl.BlockSpec((1, bk, bn), lambda e, i, j, k: (e, k, j)),
-        pl.BlockSpec((1, bk, bn), lambda e, i, j, k: (e, k, j)),
-        pl.BlockSpec((1, bm, bn), lambda e, i, j, k: (e, i, j)),
-    ]
-    for rows, cols in plan.table_specs():
-        in_specs.append(pl.BlockSpec((rows, cols), lambda e, i, j, k: (0, 0)))
-
-    dzg, dzu = pl.pallas_call(
-        functools.partial(_moe_bwd_kernel, plan=plan, nk=nk),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, bm, bn), lambda e, i, j, k: (e, i, j))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((E, Cp, Np), jnp.float32)] * 2,
-        scratch_shapes=[
-            pltpu.VMEM((bm, bn), jnp.float32),
-            pltpu.VMEM((bm, bn), jnp.float32),
-        ],
-        interpret=interpret,
-    )(xp, wgp, wup, gp, *tables)
-    return dzg[:, :C, :N], dzu[:, :C, :N]
+@functools.partial(jax.jit, static_argnames=("plan", "bm", "interpret"))
+def _glu_call(x, wg, wu, tables, te, nu, *, plan, bm, interpret):
+    (m, k), n = x.shape, wg.shape[2]
+    bn = _block_n(n)
+    return pl.pallas_call(
+        functools.partial(_glu_kernel, plan=plan),
+        grid_spec=_grid_spec(2, plan.table_specs(), m, k, n, bm, bn),
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        interpret=interpret, name="moe_glu",
+    )(te, nu, x, wg, wu, *tables)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _moe_glu_op(x, wg, wu, tables, plan, block, interpret, impl_bwd):
-    return _fused_moe_glu_3d(x, wg, wu, tables, plan=plan, block=block,
-                             interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("plan", "bm", "interpret"))
+def _glu_dz_call(x, wg, wu, g, tables, te, nu, *, plan, bm, interpret):
+    """(dzg, dzu) of the grouped GLU in one pass; each (M, N) f32."""
+    (m, k), n = x.shape, wg.shape[2]
+    bn = _block_n(n)
+    return pl.pallas_call(
+        functools.partial(_glu_dz_kernel, plan=plan),
+        grid_spec=_grid_spec(2, plan.table_specs(), m, k, n, bm, bn,
+                             extra_row_inputs=1, n_out=2),
+        out_shape=[jax.ShapeDtypeStruct((m, n), jnp.float32)] * 2,
+        interpret=interpret, name="moe_glu_dz",
+    )(te, nu, x, wg, wu, g.astype(jnp.float32), *tables)
 
 
-def _moe_glu_op_fwd(x, wg, wu, tables, plan, block, interpret, impl_bwd):
-    y = _moe_glu_op(x, wg, wu, tables, plan, block, interpret, impl_bwd)
-    return y, (x, wg, wu, tables)
+@functools.partial(jax.jit, static_argnames=("bm", "interpret"))
+def _down_call(x, w, te, nu, *, bm, interpret):
+    (m, k), n = x.shape, w.shape[2]
+    bn = _block_n(n)
+    return pl.pallas_call(
+        _down_kernel,
+        grid_spec=_grid_spec(1, (), m, k, n, bm, bn),
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        interpret=interpret, name="moe_down",
+    )(te, nu, x, w)
 
 
-def _moe_glu_op_bwd(plan, block, interpret, impl_bwd, res, g):
-    x, wg, wu, tables = res
-    xf, wgf, wuf, gf = (a.astype(jnp.float32) for a in (x, wg, wu, g))
+# --- autodiff: fused forward; (dzg, dzu) fused or recomputed; dx and the
+# expert weights' gradients from the grouped product's own transpose
+# (``jax.lax.ragged_dot`` over the same groups: padding rows are zero, so
+# they add nothing)
+
+
+def _ragged_vjp(x, w, sizes, dz):
+    """(dx, dw) of ``ragged_dot(x, w, sizes)`` at cotangent ``dz``."""
+    _, vjp = jax.vjp(lambda a, b: jax.lax.ragged_dot(
+        a, b, sizes, preferred_element_type=jnp.float32), x, w)
+    return vjp(dz)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _glu_op(x, wg, wu, tables, sizes, tiles, plan, bm, interpret, impl_bwd):
+    return _glu_call(x, wg, wu, tables, *tiles, plan=plan, bm=bm,
+                     interpret=interpret)
+
+
+def _glu_op_fwd(x, wg, wu, tables, sizes, tiles, plan, bm, interpret,
+                impl_bwd):
+    y = _glu_op(x, wg, wu, tables, sizes, tiles, plan, bm, interpret,
+                impl_bwd)
+    return y, (x, wg, wu, tables, sizes, tiles)
+
+
+def _glu_op_bwd(plan, bm, interpret, impl_bwd, res, g):
+    x, wg, wu, tables, sizes, tiles = res
+    xf, wgf, wuf = (a.astype(jnp.float32) for a in (x, wg, wu))
     if impl_bwd == "fused":
-        dzg, dzu = _moe_dz_3d(x, wg, wu, g, tables, plan=plan, block=block,
-                              interpret=interpret)
+        dzg, dzu = _glu_dz_call(x, wg, wu, g, tables, *tiles, plan=plan,
+                                bm=bm, interpret=interpret)
     else:
-        zg = jnp.einsum("ecd,edf->ecf", xf, wgf)
-        zu = jnp.einsum("ecd,edf->ecf", xf, wuf)
+        zg = jax.lax.ragged_dot(xf, wgf, sizes)
+        zu = jax.lax.ragged_dot(xf, wuf, sizes)
         act_zg, slope = plan_value_and_slope(plan, tables, zg)
-        dzg = gf * zu * slope
-        dzu = gf * act_zg
-    dx = (
-        jnp.einsum("ecf,edf->ecd", dzg, wgf)
-        + jnp.einsum("ecf,edf->ecd", dzu, wuf)
-    ).astype(x.dtype)
-    dwg = jnp.einsum("ecd,ecf->edf", xf, dzg).astype(wg.dtype)
-    dwu = jnp.einsum("ecd,ecf->edf", xf, dzu).astype(wu.dtype)
+        gf = g.astype(jnp.float32)
+        dzg, dzu = gf * zu * slope, gf * act_zg
+    dxg, dwg = _ragged_vjp(xf, wgf, sizes, dzg)
+    dxu, dwu = _ragged_vjp(xf, wuf, sizes, dzu)
     dtables = jax.tree_util.tree_map(jnp.zeros_like, tables)
-    return dx, dwg, dwu, dtables
+    return ((dxg + dxu).astype(x.dtype), dwg.astype(wg.dtype),
+            dwu.astype(wu.dtype), dtables, None, None)
 
 
-_moe_glu_op.defvjp(_moe_glu_op_fwd, _moe_glu_op_bwd)
+_glu_op.defvjp(_glu_op_fwd, _glu_op_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _down_op(x, w, sizes, tiles, bm, interpret):
+    return _down_call(x, w, *tiles, bm=bm, interpret=interpret)
+
+
+def _down_op_fwd(x, w, sizes, tiles, bm, interpret):
+    return _down_op(x, w, sizes, tiles, bm, interpret), (x, w, sizes)
+
+
+def _down_op_bwd(bm, interpret, res, g):
+    x, w, sizes = res
+    dx, dw = _ragged_vjp(x.astype(jnp.float32), w.astype(jnp.float32),
+                         sizes, g.astype(jnp.float32))
+    return dx.astype(x.dtype), dw.astype(w.dtype), None, None
+
+
+_down_op.defvjp(_down_op_fwd, _down_op_bwd)
+
+
+def _check_layout(x, group_sizes, bm: int):
+    if x.shape[0] % bm:
+        raise ValueError(f"{x.shape[0]} rows are not a multiple of the row "
+                         f"tile {bm}")
+    return tile_experts(group_sizes.astype(jnp.int32), x.shape[0] // bm, bm)
 
 
 def fused_moe_glu(
     x: jax.Array,
     w_gate: jax.Array,
     w_up: jax.Array,
+    group_sizes: jax.Array,
     *,
+    row_tile: int,
     table: PWLTable | None = None,
     act: str | None = None,
-    block=DEFAULT_BLOCK,
     interpret: bool | None = None,
     impl_bwd: str | None = None,
 ) -> jax.Array:
-    """Per-expert ``act(x[e] @ w_gate[e]) * (x[e] @ w_up[e])`` in one pass.
+    """Grouped ``act(x_r @ w_gate[e]) * (x_r @ w_up[e])`` in one pass.
 
-    x: (E, C, K) dispatched expert buckets;  w_gate/w_up: (E, K, N).
-    Epilogue selection as in :func:`fused_glu` (table -> PWL, act -> exact,
-    neither -> identity / plain bilinear GLU).  ``impl_bwd`` as in
-    :func:`fused_linear`.  Returns (E, C, N).
+    x: (M, K) rows grouped by expert: expert ``e``'s ``group_sizes[e]`` rows
+    follow expert ``e - 1``'s, every group size a multiple of ``row_tile``
+    (pad groups with zero rows), rows past the last group are ignored and
+    come back zero.  w_gate/w_up: (E, K, N).  Epilogue selection as in
+    :func:`fused_glu` (table -> PWL, act -> exact, neither -> identity).
+    ``impl_bwd`` as in :func:`fused_linear`.  Returns (M, N).
     """
     if interpret is None:
         interpret = should_interpret()
     plan, tables = plan_and_operands(table, act)
-    return _moe_glu_op(x, w_gate, w_up, tables, plan, block, interpret,
-                       resolve_impl_bwd(impl_bwd))
+    tiles = _check_layout(x, group_sizes, row_tile)
+    return _glu_op(x, w_gate, w_up, tables, group_sizes.astype(jnp.int32),
+                   tiles, plan, row_tile, interpret,
+                   resolve_impl_bwd(impl_bwd))
+
+
+def moe_down(
+    x: jax.Array,
+    w: jax.Array,
+    group_sizes: jax.Array,
+    *,
+    row_tile: int,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Grouped ``x_r @ w[e]`` over the layout :func:`fused_moe_glu` takes:
+    x (M, K), w (E, K, N).  Returns (M, N)."""
+    if interpret is None:
+        interpret = should_interpret()
+    tiles = _check_layout(x, group_sizes, row_tile)
+    return _down_op(x, w, group_sizes.astype(jnp.int32), tiles, row_tile,
+                    interpret)

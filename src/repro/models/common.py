@@ -45,6 +45,10 @@ class ModelConfig:
     activation: str = "silu"
     mlp_type: str = "swiglu"          # swiglu | geglu | mlp
     norm_type: str = "rmsnorm"        # rmsnorm | layernorm | nonparam_ln
+    norm_eps: float = 1e-6            # rmsnorm's epsilon (and QK-norm's)
+    # RMSNorm over the whole q and the whole k projection (H*dh and Hkv*dh
+    # wide, learned scales) before RoPE (olmoe)
+    qk_norm: bool = False
     qkv_bias: bool = False
     act_impl: str = "exact"           # exact | jnp | kernel | fused (sfu.IMPLS)
     act_breakpoints: int = 32
@@ -52,7 +56,7 @@ class ModelConfig:
     # applied last (last-match-wins) over the act_impl translation — e.g.
     # mamba2 pins ("ssm:silu", ApproxSpec(fn="silu", impl="exact")) because
     # SSM-input activations amplify approximation error through the
-    # recurrence (EXPERIMENTS.md "SSM sensitivity" study).
+    # recurrence.
     act_site_specs: tuple = ()
     pwl_softmax: bool = False         # PWL-exp softmax (paper Sec. V-B)
     # PWL table storage format ("f32" | "bf16" | "f16"): the paper's
@@ -78,7 +82,9 @@ class ModelConfig:
     n_experts: int = 0
     n_active_experts: int = 0
     moe_d_ff: int = 0
-    capacity_factor: float = 1.25
+    # renormalise each token's top-k router weights to sum to one (False:
+    # the softmax probabilities themselves, as olmoe publishes)
+    norm_topk: bool = True
     # SSM (mamba2)
     ssm_state: int = 0
     ssm_head_dim: int = 64

@@ -70,9 +70,21 @@ def nonparam_ln(x, eps=1e-5):
     return ((xf - mu) * jax.lax.rsqrt(var + eps)).astype(x.dtype)
 
 
+def qk_rms_norm(x, scale, eps: float):
+    """RMSNorm of each position's whole projection ``x`` (..., H, dh), all
+    heads together, with the learned ``scale`` (H*dh,) stored as an offset
+    from 1 as :func:`rms_norm` stores it.  Under a mesh whose model axis
+    splits the heads the mean of squares reduces across it."""
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(xf), axis=(-2, -1), keepdims=True)
+    y = xf * jax.lax.rsqrt(var + eps)
+    s = 1.0 + scale.astype(jnp.float32).reshape(x.shape[-2:])
+    return (y * s).astype(x.dtype)
+
+
 def apply_norm(cfg: ModelConfig, params, x):
     if cfg.norm_type == "rmsnorm":
-        return rms_norm(x, params["scale"])
+        return rms_norm(x, params["scale"], cfg.norm_eps)
     if cfg.norm_type == "layernorm":
         return layer_norm(x, params["scale"], params["bias"])
     if cfg.norm_type == "nonparam_ln":
@@ -929,6 +941,10 @@ def attention_layer(
             v = v + params["bv"].astype(dtype)
     else:
         k, v = cross_kv
+    if cfg.qk_norm:
+        q = qk_rms_norm(q, params["q_norm"]["scale"], cfg.norm_eps)
+        if cross_kv is None:
+            k = qk_rms_norm(k, params["k_norm"]["scale"], cfg.norm_eps)
 
     if positions is None:
         off = 0 if cache_pos is None else cache_pos

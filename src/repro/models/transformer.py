@@ -67,6 +67,9 @@ def attn_defs(cfg: ModelConfig):
         defs["bq"] = ParamDef((H, dh), ("heads", None), init="zeros")
         defs["bk"] = ParamDef((Hkv, dh), ("kv", None), init="zeros")
         defs["bv"] = ParamDef((Hkv, dh), ("kv", None), init="zeros")
+    if cfg.qk_norm:
+        defs["q_norm"] = {"scale": ParamDef((H * dh,), (None,), init="zeros")}
+        defs["k_norm"] = {"scale": ParamDef((Hkv * dh,), (None,), init="zeros")}
     return defs
 
 
@@ -157,7 +160,9 @@ def model_defs(cfg: ModelConfig):
 
 def block_apply(cfg: ModelConfig, p, h, mixer: str, ffn: str, cache=None,
                 pos=None, plan=None, paged=None):
-    """Pre-norm residual block.  Returns (h, new_cache, aux_loss).
+    """Pre-norm residual block.  Returns (h, new_cache, aux_loss,
+    expert_rows): the rows each expert computed, (ranks, E / ranks) int32
+    as ``moe_layer`` gives them, for an MoE block, None for a dense one.
 
     ``plan`` is the compiled activation plan threaded down from the forward
     entry points (one ``sfu.plan_for`` per trace, not per layer);
@@ -175,10 +180,10 @@ def block_apply(cfg: ModelConfig, p, h, mixer: str, ffn: str, cache=None,
     h = h + y
     hn2 = L.apply_norm(cfg, p["ln2"], h)
     if ffn == "moe":
-        y2, aux = MOE.moe_layer(cfg, p["ffn"], hn2, plan=plan)
+        y2, aux, rows = MOE.moe_layer(cfg, p["ffn"], hn2, plan=plan)
     else:
-        y2, aux = L.mlp(cfg, p["ffn"], hn2, plan=plan), jnp.float32(0.0)
-    return h + y2, new_cache, aux
+        y2, aux, rows = L.mlp(cfg, p["ffn"], hn2, plan=plan), 0.0, None
+    return h + y2, new_cache, aux, rows
 
 
 # ---------------------------------------------------------------------------
@@ -224,7 +229,7 @@ def forward(cfg: ModelConfig, params, tokens, vision_embeds=None):
     def period_fn(carry, stacked):
         h, aux = carry
         for j in range(period):
-            h, _, a = block_apply(cfg, stacked[j], h, *kinds[j], plan=plan)
+            h, _, a, _ = block_apply(cfg, stacked[j], h, *kinds[j], plan=plan)
             aux = aux + a
         return (h, aux), None
 
@@ -319,9 +324,7 @@ def make_cache(cfg: ModelConfig, batch: int, max_len: int):
     return init_params(cache_defs(cfg, batch, max_len), jax.random.PRNGKey(0))
 
 
-def _scan_with_cache(cfg: ModelConfig, params, h, cache, pos, paged=None):
-    if "k_pages" in cache[0]:
-        return _scan_with_pools(cfg, params, h, cache, pos, paged)
+def _scan_with_cache(cfg: ModelConfig, params, h, cache, pos):
     kinds = cfg.layer_kinds
     period = cfg.period
     plan = sfu.plan_for(cfg)
@@ -335,7 +338,7 @@ def _scan_with_cache(cfg: ModelConfig, params, h, cache, pos, paged=None):
         new_caches = []
         with sfu.guard.capture() as cap:
             for j in range(period):
-                h, nc, _ = block_apply(
+                h, nc, _, _ = block_apply(
                     cfg, stacked[j], h, *kinds[j], cache=cache_p[j], pos=pos,
                     plan=plan,
                 )
@@ -365,38 +368,56 @@ def _scan_with_pools(cfg: ModelConfig, params, h, pools, pos, paged):
     the period index.  A dense per-layer cache is small and rewritten, so it
     rides xs/ys (:func:`_scan_with_cache`); a shared pool is large and gains
     one token a step, and slicing it out and stacking it back would copy
-    every layer's pool twice per step."""
+    every layer's pool twice per step.  Returns (h, pools, expert_rows), the
+    last as :func:`_layer_rows` gives it."""
     kinds = cfg.layer_kinds
     period = cfg.period
     plan = sfu.plan_for(cfg)
 
     # `paged` (page_table + kv_len) is shared by every layer, so it enters
     # the scan body as a closure constant; guard counters leave as ys, as in
-    # _scan_with_cache.
+    # _scan_with_cache, and so do the MoE blocks' rows per expert.
     def period_fn(carry, stacked, i):
         h, pools = carry
         pools = list(pools)
+        rows = []
         with sfu.guard.capture() as cap:
             for j in range(period):
-                h, pools[j], _ = block_apply(
+                h, pools[j], _, r = block_apply(
                     cfg, stacked[j], h, *kinds[j], cache=pools[j], pos=pos,
                     plan=plan, paged={**paged, "layer": i},
                 )
-        return (h, pools), cap.result()
+                rows.append(r)
+        return (h, pools), (cap.result(), rows)
 
     n_periods = cfg.n_layers // period
     if cfg.scan_layers:
-        (h, pools), gcounts = jax.lax.scan(
+        (h, pools), (gcounts, rows) = jax.lax.scan(
             lambda carry, xs: period_fn(carry, *xs), (h, pools),
             (params["layers"], jnp.arange(n_periods, dtype=jnp.int32)),
         )
         sfu.guard.emit(gcounts)
-        return h, pools
-    for i in range(n_periods):
-        stacked = jax.tree_util.tree_map(lambda x: x[i], params["layers"])
-        (h, pools), gcounts = period_fn((h, pools), stacked, i)
-        sfu.guard.emit(gcounts)
-    return h, pools
+    else:
+        steps = []
+        for i in range(n_periods):
+            stacked = jax.tree_util.tree_map(lambda x: x[i], params["layers"])
+            (h, pools), (gcounts, r) = period_fn((h, pools), stacked, i)
+            sfu.guard.emit(gcounts)
+            steps.append(r)
+        rows = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *steps)
+    return h, pools, _layer_rows(rows)
+
+
+def _layer_rows(rows):
+    """The MoE blocks' rows per expert, (n_moe_layers, ranks, E / ranks) in
+    layer order, from the scan's per-period-slot (n_periods, ranks,
+    E / ranks) stacks (None for a dense slot); None when no block is MoE."""
+    if all(r is None for r in rows):
+        return None
+    # (n_periods, period, ranks, E_loc) -> layers in order, dense slots
+    # left out
+    stacked = jnp.stack([r for r in rows if r is not None], axis=1)
+    return stacked.reshape(-1, *stacked.shape[2:])
 
 
 def prefill(cfg: ModelConfig, params, tokens, cache, vision_embeds=None):
@@ -456,10 +477,12 @@ def prefill_paged(cfg: ModelConfig, params, tokens, cache, page_table,
     """Prompt prefill into a paged cache.  tokens: (B, S) with S a multiple
     of the page size (engine-bucketed; rows padded past ``lengths`` are
     causal-masked by position).  Returns (logits at position lengths-1,
-    cache) — the logits of each request's true last prompt token.
+    cache, expert_rows) — the logits of each request's true last prompt
+    token, and the rows each expert computed in each MoE layer,
+    (n_moe_layers, ranks, E / ranks) int32 (None for a dense model).
     """
     h = embed_tokens(cfg, params, tokens)
-    h, new_cache = _scan_with_cache(
+    h, new_cache, rows = _scan_with_pools(
         cfg, params, h, cache, pos=0, paged={"page_table": page_table}
     )
     h = L.apply_norm(cfg, params["final_norm"], h)
@@ -469,7 +492,7 @@ def prefill_paged(cfg: ModelConfig, params, tokens, cache, page_table,
         logits, jnp.broadcast_to(idx, (logits.shape[0], 1, logits.shape[2])),
         axis=1,
     )
-    return last, new_cache
+    return last, new_cache, rows
 
 
 def decode_step_paged(cfg: ModelConfig, params, tokens, cache, page_table,
@@ -477,11 +500,12 @@ def decode_step_paged(cfg: ModelConfig, params, tokens, cache, page_table,
     """One-token decode over the paged cache.  tokens: (B, 1);
     kv_len: (B,) per-request depths (the new token's position — continuous
     batching runs every slot at its own depth).  Appends in place, attends
-    through the page table.  Returns (logits, cache)."""
+    through the page table.  Returns (logits, cache, expert_rows), the last
+    as :func:`prefill_paged` gives it."""
     h = embed_tokens(cfg, params, tokens)
-    h, new_cache = _scan_with_cache(
+    h, new_cache, rows = _scan_with_pools(
         cfg, params, h, cache, pos=kv_len,
         paged={"page_table": page_table, "kv_len": kv_len},
     )
     h = L.apply_norm(cfg, params["final_norm"], h)
-    return unembed(cfg, params, h), new_cache
+    return unembed(cfg, params, h), new_cache, rows

@@ -139,6 +139,7 @@ class PagedServingEngine:
         self.wall_clock_budget_s = wall_clock_budget_s
         self.health = new_health(policy, guard)
         self._fns = self._build_fns()
+        self._rows = None  # the last step's rows per expert, on the device
         self._nan_fns_cache: dict = {}
         self._degraded_cache: dict = {}
         self.decode_steps = 0
@@ -146,11 +147,14 @@ class PagedServingEngine:
 
     # -- jitted program variants ---------------------------------------------
     def _build_fns(self, plan_override=None, inject_site: Optional[str] = None):
-        """Jitted prefill/decode wrappers returning ``(logits, cache, diag)``
-        where ``diag`` is the ``sfu.guard`` per-site ``{key: int32[2]}``
-        counter dict ({} when the guard is off).  ``plan_override`` swaps the
-        activation plan (the degraded re-run path); ``inject_site`` arms the
-        trace-time NaN fault for one site."""
+        """Jitted prefill/decode wrappers returning ``(logits, cache, diag,
+        expert_rows)`` where ``diag`` is the ``sfu.guard`` per-site
+        ``{key: int32[2]}`` counter dict ({} when the guard is off) and
+        ``expert_rows`` the rows each expert computed in each MoE layer, by
+        the rank that holds it, ``(n_moe_layers, ranks, E / ranks)`` int32
+        (None for a dense model).
+        ``plan_override`` swaps the activation plan (the degraded re-run
+        path); ``inject_site`` arms the trace-time NaN fault for one site."""
         model = self.model
         if plan_override is not None:
             model = Model(dataclasses.replace(self.model.cfg,
@@ -169,9 +173,10 @@ class PagedServingEngine:
                         stack.enter_context(sfu.guard.force_nan(inject_site))
                     col = (stack.enter_context(sfu.guard.collecting())
                            if guard_on else None)
-                    logits, new_cache = fn(params, toks, cache, pt, lens)
+                    logits, new_cache, rows = fn(params, toks, cache, pt,
+                                                 lens)
                 diag = col.result() if col is not None else {}
-                return logits, new_cache, diag
+                return logits, new_cache, diag, rows
 
             # the name the profiler's trace and the compiled module carry
             call.__name__ = call.__qualname__ = f"serving_{phase}"
@@ -259,7 +264,9 @@ class PagedServingEngine:
         """Run one prefill/decode step with fault injection and guard
         degradation.  jax.jit does not donate inputs, so ``self.cache`` (an
         element of ``args``) stays valid across re-runs — a degraded re-run
-        replays the exact same step."""
+        replays the exact same step.  Returns ``(logits, cache)``; the
+        step's rows per expert stay on the device in ``self._rows`` until
+        they are read with the sampled tokens."""
         nan_site = None
         if phase == "decode" and self.faults is not None:
             nan_site = self.faults.nan_site_due()
@@ -267,7 +274,8 @@ class PagedServingEngine:
         if nan_site is not None:
             self._incident("nan_injected", site=nan_site,
                            step=self.decode_steps)
-        logits, cache2, diag = self._device_call(fns[phase], args, phase)
+        logits, cache2, diag, self._rows = self._device_call(
+            fns[phase], args, phase)
         bad = self._scan_diag(diag, accumulate=True)
         for impl in ("jnp", "exact"):
             if not bad:
@@ -278,7 +286,8 @@ class PagedServingEngine:
                            sites=list(bad), degraded_to=impl,
                            step=self.decode_steps)
             dfns = self._degraded_fns(tuple(bad), impl)
-            logits, cache2, diag = self._device_call(dfns[phase], args, phase)
+            logits, cache2, diag, self._rows = self._device_call(
+                dfns[phase], args, phase)
             still = self._scan_diag(diag, accumulate=False)
             rec = self.health["nonfinite_recoveries"]
             for k in bad:
@@ -298,10 +307,11 @@ class PagedServingEngine:
         n = len(adm.prefill_tokens)
         bucket = max(self.page_size, _next_pow2(n))
         with tracing.span("serving.prefill", request_id=adm.request.request_id,
-                          tokens=n, bucket=bucket):
-            return self._prefill_bucket(adm, n, bucket)
+                          tokens=n, bucket=bucket) as sp:
+            return self._prefill_bucket(adm, n, bucket, sp.attrs)
 
-    def _prefill_bucket(self, adm: Admission, n: int, bucket: int) -> bool:
+    def _prefill_bucket(self, adm: Admission, n: int, bucket: int,
+                        counters: dict) -> bool:
         slot = adm.slot
         with tracing.span("serving.prefill.inputs"):
             npg = bucket // self.page_size
@@ -322,7 +332,9 @@ class PagedServingEngine:
             self._cur[slot] = adm.resume_tokens[-1]
             return False
         with tracing.span("serving.prefill.sample"):
-            tok = int(np.asarray(jnp.argmax(logits[0, 0])))
+            tok, rows = jax.device_get((jnp.argmax(logits[0, 0]), self._rows))
+            counters.update(self._routing_counters(rows, bucket))
+        tok = int(tok)
         self._cur[slot] = tok
         self.generated += 1
         if self.sched.record_prefill_token(slot, tok):
@@ -377,7 +389,9 @@ class PagedServingEngine:
         """The body of :meth:`decode_step`; sets the step's ``counters``:
         slots ``active``, page-table width ``n_cols``, ``tokens_held`` in
         the cache for the active slots and ``token_capacity``, the tokens
-        their pages and the pool's reservations for them could hold."""
+        their pages and the pool's reservations for them could hold, and
+        for an MoE model the routing counters of
+        :meth:`_routing_counters`."""
         if self.faults is not None:
             self.faults.set_step(self.decode_steps)
         active = self.sched.active_slots()
@@ -418,8 +432,10 @@ class PagedServingEngine:
             return []
         self.cache = cache2
         with tracing.span("serving.decode.sample"):
-            nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1)).astype(
-                np.int32)
+            nxt, rows = jax.device_get(
+                (jnp.argmax(logits[:, 0], axis=-1), self._rows))
+            nxt = nxt.astype(np.int32)
+            counters.update(self._routing_counters(rows, self.max_slots))
         with tracing.span("serving.decode.commit"):
             self.sched.tick()
             self.decode_steps += 1
@@ -433,6 +449,27 @@ class PagedServingEngine:
                     self._evict(i)
                     finished.append(i)
         return finished
+
+    def _routing_counters(self, rows, tokens: int) -> dict:
+        """Counters of a step's MoE routing over ``tokens`` positions (every
+        slot, or every position of the prefill bucket), from the rows each
+        expert computed, ``(n_moe_layers, ranks, E / ranks)``:
+        ``routed_rows``, the (token, choice) rows routed over every layer,
+        ``tokens`` x top-k x MoE layers; ``expert_rows_max``, the most rows
+        one expert took in one layer; ``rank_rows`` and ``rank_experts``,
+        per rank of the model axis (one rank unless the experts shard over
+        it), the rows its layout held and its (layer, expert) pairs that
+        took a row, summed over layers.  Dropless routing computes every
+        routed row: the ranks' rows add up to ``routed_rows``.  Empty for a
+        dense model."""
+        if rows is None:
+            return {}
+        rows = np.asarray(rows)
+        k = self.model.cfg.n_active_experts
+        return {"routed_rows": tokens * k * rows.shape[0],
+                "expert_rows_max": int(rows.max()),
+                "rank_rows": rows.sum(axis=(0, 2)).tolist(),
+                "rank_experts": (rows > 0).sum(axis=(0, 2)).tolist()}
 
     # -- deadlines ------------------------------------------------------------
     def _expire_deadlines(self) -> None:

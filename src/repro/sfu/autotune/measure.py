@@ -215,22 +215,25 @@ def _latency_thunk(spec: ApproxSpec, block, wl: SiteWorkload):
         return run_jnp, (q, k, v)
 
     if site == SITE_MOE:
+        from repro.kernels.fused import moe as M
+
         kx, kg, ku = jax.random.split(key, 3)
+        # every expert takes `expert_capacity` rows, grouped by expert
+        bm = M.row_tile(wl.expert_capacity)
+        rows = -(-wl.expert_capacity // bm) * bm
+        sizes = jnp.full((wl.n_experts,), rows, jnp.int32)
         x = jax.random.normal(
-            kx, (wl.n_experts, wl.expert_capacity, wl.d_model), jnp.float32)
+            kx, (wl.n_experts * rows, wl.d_model), jnp.float32)
         wg = jax.random.normal(
             kg, (wl.n_experts, wl.d_model, wl.d_ff), jnp.float32) * 0.02
         wu = jax.random.normal(
             ku, (wl.n_experts, wl.d_model, wl.d_ff), jnp.float32) * 0.02
         if spec.impl == "fused":
-            from repro.kernels.fused import moe as M
-
-            blk = block if block is not None else M.DEFAULT_BLOCK
             table = get_store().get(spec)
 
             @jax.jit
-            def run_fused(x, wg, wu, _t=table, _b=tuple(blk)):
-                return M.fused_moe_glu(x, wg, wu, table=_t, block=_b)
+            def run_fused(x, wg, wu, _t=table):
+                return M.fused_moe_glu(x, wg, wu, sizes, row_tile=bm, table=_t)
 
             return run_fused, (x, wg, wu)
 
@@ -238,8 +241,8 @@ def _latency_thunk(spec: ApproxSpec, block, wl: SiteWorkload):
 
         @jax.jit
         def run_jnp(x, wg, wu):
-            return act(jnp.einsum("eck,ekn->ecn", x, wg)) * \
-                jnp.einsum("eck,ekn->ecn", x, wu)
+            return act(jax.lax.ragged_dot(x, wg, sizes)) * \
+                jax.lax.ragged_dot(x, wu, sizes)
 
         return run_jnp, (x, wg, wu)
 

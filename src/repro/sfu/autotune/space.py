@@ -25,7 +25,7 @@ tie-breaks on that order, which makes a warm-cache re-run byte-identical.
 """
 from __future__ import annotations
 
-from repro.sfu.plan import FUSED_SITES, SITE_SOFTMAX
+from repro.sfu.plan import FUSED_SITES, SITE_MOE, SITE_SOFTMAX
 from repro.sfu.spec import DEFAULT_FIT, ApproxSpec
 
 # breakpoint counts with shipped artifacts (see src/repro/core/tables/)
@@ -39,7 +39,7 @@ DTYPE_SWEEP_QUICK = ("f32", "int8")
 # latency tie, and "fused" is the paper's headline configuration
 IMPL_SWEEP = ("fused", "jnp", "exact")
 
-# (bm, bn, bk) accumulator/epilogue tiles for fused_linear/glu/moe_glu.
+# (bm, bn, bk) accumulator/epilogue tiles for fused_linear/glu.
 # The middle entry is kernels' DEFAULT_BLOCK — always swept so the chosen
 # block is never worse than the default.
 EPILOGUE_BLOCKS = ((128, 128, 256), (256, 256, 512), (512, 256, 512))
@@ -83,7 +83,8 @@ def blocks_for(site: str, impl: str, *, quick: bool = False) -> tuple:
     Non-fused impls have no tile parameter — they get the single ``None``
     block so the measurement loop stays uniform.
     """
-    if impl != "fused":
+    if impl != "fused" or site == SITE_MOE:
+        # the grouped MoE kernel sizes its own tiles from the rows per expert
         return (None,)
     if site == SITE_SOFTMAX:
         return FLASH_BLOCKS_QUICK if quick else FLASH_BLOCKS

@@ -118,7 +118,7 @@ def test_moe_expert_parallel_2dev():
         from repro.distributed.sharding import make_rules, use_rules
 
         import jax.numpy as _jnp
-        cfg = get_reduced_config("olmoe-1b-7b", act_impl="exact", capacity_factor=8.0,
+        cfg = get_reduced_config("olmoe-1b-7b", act_impl="exact",
                                  dtype=_jnp.float32)
         model = Model(cfg)
         params = model.init(jax.random.PRNGKey(0))

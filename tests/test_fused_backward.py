@@ -130,12 +130,15 @@ def test_glu_grad_parity(table_dtype):
 
 def test_moe_grad_parity():
     table = _table("silu")
-    x = _igrid(0, (3, 19, 33))
+    # three experts' groups of 32, 16 and 0 rows (row tile 16), then 16
+    # rows past the last group
+    x = _igrid(0, (64, 33))
     wg = _igrid(1, (3, 33, 24), span=4)
     wu = _igrid(2, (3, 33, 24), span=4)
+    sizes = jnp.asarray([32, 16, 0], jnp.int32)
     _parity(
         lambda x, wg, wu, **kw: fused.fused_moe_glu(
-            x, wg, wu, table=table, block=BLK, **kw
+            x, wg, wu, sizes, row_tile=16, table=table, **kw
         ),
         x, wg, wu, rel=1e-6,
     )

@@ -45,7 +45,32 @@ def _fresh_fallback_state():
 
 
 # ---------------------------------------------------------------------------
-# fused_moe_glu kernel
+# fused_moe_glu kernel: rows grouped by expert, each group a multiple of the
+# row tile; rows past the last group come back zero
+
+TILE = 16
+
+
+def _grouped(key, e, c, d, f, dtype=jnp.float32, scale=2.0):
+    """x (M, d) grouped over ``e`` experts of about ``c`` rows (uneven,
+    some groups empty, each padded to the row tile) with trailing rows
+    past the last group, the weights, the group sizes, and each row's
+    expert (-1 past the groups)."""
+    sizes = [-(-max(0, c - 7 * i) // TILE) * TILE for i in range(e)]
+    m = sum(sizes) + 2 * TILE
+    x = _rand(key, (m, d), dtype, scale)
+    wg = _rand(key + 1, (e, d, f), dtype, 0.2)
+    wu = _rand(key + 2, (e, d, f), dtype, 0.2)
+    eid = np.concatenate([np.full(n, i) for i, n in enumerate(sizes)]
+                         + [np.full(2 * TILE, -1)]).astype(np.int32)
+    return x, wg, wu, jnp.asarray(sizes, jnp.int32), eid
+
+
+def _per_row(x, w, eid):
+    """Each row times its own expert's matrix; zero past the groups."""
+    xf, wf = x.astype(jnp.float32), w.astype(jnp.float32)
+    y = jnp.einsum("md,mdf->mf", xf, wf[np.maximum(eid, 0)])
+    return jnp.where((eid >= 0)[:, None], y, 0.0)
 
 
 @pytest.mark.parametrize(
@@ -53,39 +78,29 @@ def _fresh_fallback_state():
 )
 def test_fused_moe_glu_matches_ref_shapes(e, c, d, f):
     table = _table()
-    x = _rand(0, (e, c, d), scale=2.0)
-    wg = _rand(1, (e, d, f), scale=0.2)
-    wu = _rand(2, (e, d, f), scale=0.2)
-    y = fused.fused_moe_glu(x, wg, wu, table=table, block=BLK)
-    ref = pwl.eval_coeff(jnp.einsum("ecd,edf->ecf", x, wg), table) * jnp.einsum(
-        "ecd,edf->ecf", x, wu
-    )
+    x, wg, wu, sizes, eid = _grouped(0, e, c, d, f)
+    y = fused.fused_moe_glu(x, wg, wu, sizes, row_tile=TILE, table=table)
+    ref = pwl.eval_coeff(_per_row(x, wg, eid), table) * _per_row(x, wu, eid)
     np.testing.assert_allclose(y, ref, atol=1e-5, rtol=1e-5)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_fused_moe_glu_dtypes(dtype):
     table = _table()
-    x = _rand(0, (2, 24, 48), dtype, scale=2.0)
-    wg = _rand(1, (2, 48, 56), dtype, scale=0.2)
-    wu = _rand(2, (2, 48, 56), dtype, scale=0.2)
-    y = fused.fused_moe_glu(x, wg, wu, table=table, block=BLK)
-    assert y.dtype == dtype and y.shape == (2, 24, 56)
-    xf, wgf, wuf = (a.astype(jnp.float32) for a in (x, wg, wu))
-    ref = pwl.eval_coeff(jnp.einsum("ecd,edf->ecf", xf, wgf), table) * jnp.einsum(
-        "ecd,edf->ecf", xf, wuf
-    )
+    x, wg, wu, sizes, eid = _grouped(0, 2, 24, 48, 56, dtype)
+    y = fused.fused_moe_glu(x, wg, wu, sizes, row_tile=TILE, table=table)
+    assert y.dtype == dtype and y.shape == (x.shape[0], 56)
+    ref = pwl.eval_coeff(_per_row(x, wg, eid), table) * _per_row(x, wu, eid)
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(y.astype(jnp.float32), ref, atol=tol, rtol=tol)
 
 
 @pytest.mark.parametrize("tdtype", ["bf16", "f16"])
 def test_fused_moe_glu_table_dtype_bound(tdtype):
-    x = _rand(0, (2, 24, 32), scale=2.0)
-    wg = _rand(1, (2, 32, 48), scale=0.2)
-    wu = _rand(2, (2, 32, 48), scale=0.2)
-    y32 = fused.fused_moe_glu(x, wg, wu, table=_table(), block=BLK)
-    yq = fused.fused_moe_glu(x, wg, wu, table=_table(dtype=tdtype), block=BLK)
+    x, wg, wu, sizes, _ = _grouped(0, 2, 24, 32, 48)
+    y32 = fused.fused_moe_glu(x, wg, wu, sizes, row_tile=TILE, table=_table())
+    yq = fused.fused_moe_glu(x, wg, wu, sizes, row_tile=TILE,
+                             table=_table(dtype=tdtype))
     # |gate error| * |up| — up values are O(1) here, so the raw bound holds
     err = float(jnp.max(jnp.abs(yq - y32)))
     assert err < BOUNDS[tdtype] * 4, f"{tdtype}: {err}"
@@ -93,11 +108,9 @@ def test_fused_moe_glu_table_dtype_bound(tdtype):
 
 def test_fused_moe_glu_single_pass_jaxpr():
     table = _table()
-    x = _rand(0, (2, 32, 32), scale=2.0)
-    wg = _rand(1, (2, 32, 32), scale=0.2)
-    wu = _rand(2, (2, 32, 32), scale=0.2)
+    x, wg, wu, sizes, _ = _grouped(0, 2, 32, 32, 32)
     jaxpr = str(jax.make_jaxpr(
-        lambda *a: fused.fused_moe_glu(*a, table=table, block=BLK)
+        lambda *a: fused.fused_moe_glu(*a, sizes, row_tile=TILE, table=table)
     )(x, wg, wu))
     assert jaxpr.count("pallas_call") == 1, jaxpr
     assert "gather" not in jaxpr, "unfused PWL dispatch leaked"
@@ -105,16 +118,15 @@ def test_fused_moe_glu_single_pass_jaxpr():
 
 def test_fused_moe_glu_grads_match_unfused():
     table = _table()
-    x = _rand(0, (2, 9, 33), scale=1.5)
-    wg = _rand(1, (2, 33, 21), scale=0.2)
-    wu = _rand(2, (2, 33, 21), scale=0.2)
+    x, wg, wu, sizes, eid = _grouped(0, 2, 9, 33, 21, scale=1.5)
 
     def fused_loss(x, wg, wu):
-        return jnp.sum(fused.fused_moe_glu(x, wg, wu, table=table, block=BLK) ** 2)
+        return jnp.sum(fused.fused_moe_glu(x, wg, wu, sizes, row_tile=TILE,
+                                           table=table) ** 2)
 
     def ref_loss(x, wg, wu):
-        g = jnp.einsum("ecd,edf->ecf", x, wg)
-        u = jnp.einsum("ecd,edf->ecf", x, wu)
+        g = _per_row(x, wg, eid)
+        u = _per_row(x, wu, eid)
         return jnp.sum((pwl.eval_coeff(g, table) * u) ** 2)
 
     g_f = jax.grad(fused_loss, argnums=(0, 1, 2))(x, wg, wu)
@@ -287,7 +299,7 @@ def test_moe_layer_fused_matches_unfused():
         for impl in ("jnp", "fused"):
             cfg = _moe_cfg(act_impl=impl)
             params = _moe_params(cfg)
-            y, aux = moe_mod.moe_layer(cfg, params, x)
+            y, aux, _ = moe_mod.moe_layer(cfg, params, x)
             outs[impl] = y
     assert not [w for w in rec if "falling back" in str(w.message)]
     np.testing.assert_allclose(outs["fused"], outs["jnp"], atol=1e-5, rtol=1e-4)
@@ -306,7 +318,7 @@ def test_moe_layer_fused_vs_unfused_all_table_dtypes(tdtype):
     for impl in ("jnp", "fused"):
         cfg = _moe_cfg(act_impl=impl, act_table_dtype=tdtype)
         params = _moe_params(cfg)
-        outs[impl], _ = moe_mod.moe_layer(cfg, params, x)
+        outs[impl], _, _ = moe_mod.moe_layer(cfg, params, x)
     np.testing.assert_allclose(
         outs["fused"], outs["jnp"], atol=BOUNDS[tdtype], rtol=0.05
     )

@@ -248,7 +248,7 @@ def test_moe_expert_parallel_fused_parity():
         from repro.models import Model
 
         cfg = get_reduced_config("olmoe-1b-7b", act_impl="fused",
-                                 capacity_factor=8.0, dtype=jnp.float32)
+                                 dtype=jnp.float32)
         model = Model(cfg)
         params = model.init(jax.random.PRNGKey(0))
         batch = {"tokens": jax.random.randint(

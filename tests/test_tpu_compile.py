@@ -25,7 +25,7 @@ from repro.serving import kv_cache
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -41,11 +41,16 @@ def one_chip():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
     if old_log_dir is None:
         os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @pytest.fixture
@@ -174,12 +179,90 @@ def test_fused_flash_attention_grad_compiles(one_chip, mosaic):
     assert "tpu_custom_call" in text
 
 
-def test_fused_moe_glu_compiles_at_olmoe_widths(one_chip, mosaic):
-    # olmoe-1b-7b: d_model 2048, expert d_ff 1024; 16 of its 64 experts (one
-    # chip's share on a model=4 mesh), 256-token capacity buckets
+def _grouped_glu_text(one_chip, rows):
+    """The grouped expert GLU at olmoe-1b-7b widths (d_model 2048, expert
+    d_ff 1024) over 16 of its 64 experts (one chip's share on a model=4
+    mesh), on ``rows`` rows laid out as the MoE layer lays them out."""
+    from repro.kernels.fused import moe as M
+
     table = _table("silu")
-    x = _struct(one_chip, (16, 256, 2048), jnp.bfloat16)
+    bm = M.row_tile(rows / 16)
+    m = -(-(rows + 16 * (bm - 1)) // bm) * bm
+    x = _struct(one_chip, (m, 2048), jnp.bfloat16)
     w = _struct(one_chip, (16, 2048, 1024), jnp.bfloat16)
-    text = _compiled_text(
-        lambda x, wg, wu: fused.fused_moe_glu(x, wg, wu, table=table), x, w, w)
+    wd = _struct(one_chip, (16, 1024, 2048), jnp.bfloat16)
+    sizes = _struct(one_chip, (16,), jnp.int32)
+
+    def ffn(x, wg, wu, wd, sizes):
+        h = fused.fused_moe_glu(x, wg, wu, sizes, row_tile=bm, table=table)
+        return M.moe_down(h, wd, sizes, row_tile=bm)
+
+    return _compiled_text(ffn, x, w, w, wd, sizes)
+
+
+def test_fused_moe_glu_compiles_at_olmoe_widths(one_chip, mosaic):
+    # decode: 64 slots x top-8 = 512 rows, ~128 of them on one rank
+    text = _grouped_glu_text(one_chip, 128)
+    assert "%moe_glu" in text and "%moe_down" in text
     assert "tpu_custom_call" in text
+
+
+def test_fused_moe_glu_compiles_at_olmoe_prefill(one_chip, mosaic):
+    # a 1024-token prefill: 8192 rows, ~2048 of them on one rank
+    text = _grouped_glu_text(one_chip, 2048)
+    assert "%moe_glu" in text and "%moe_down" in text
+    assert "tpu_custom_call" in text
+
+
+# --------------------------------------------------------------------------
+# olmoe-1b-7b served over a (data=1, model=4) mesh at published widths
+
+
+def test_olmoe_paged_decode_compiles_on_model4_mesh(v5e_2x2, mosaic):
+    """The paged decode step of the whole model (QK-norm on, dropless
+    top-8 of 64 experts, 16 per chip) compiles for four described chips:
+    the grouped kernels run per shard, the expert combine is an
+    all-reduce, and the step returns its rows per expert."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.distributed.sharding import make_rules, sanitize_spec, use_rules
+    from repro.launch.mesh import make_mesh
+    from repro.models import transformer
+    from repro.models.common import logical_specs, shape_structs
+
+    cfg = get_config("olmoe-1b-7b", act_impl="fused", pwl_softmax=True)
+    assert cfg.qk_norm and not cfg.norm_topk
+    model = Model(cfg)
+    mesh = make_mesh((1, 4), ("data", "model"), devices=v5e_2x2.devices)
+    rules = make_rules(cfg, mesh)
+
+    def placed(structs, logical):
+        return jax.tree_util.tree_map(
+            lambda s, ax: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=(
+                NamedSharding(mesh, sanitize_spec(mesh, rules.spec(*ax),
+                                                  s.shape)))),
+            structs, logical, is_leaf=lambda x: isinstance(x, tuple))
+
+    slots, page_size, pages = 8, 128, 33
+    params = placed(model.param_structs(), model.param_logical())
+    pool_defs = transformer.paged_cache_defs(cfg, pages, page_size)
+    cache = placed(shape_structs(pool_defs), logical_specs(pool_defs))
+    rep = NamedSharding(mesh, PartitionSpec())
+    i32 = jnp.int32
+
+    def step(params, toks, cache, pt, lens):
+        with use_rules(rules):
+            return model.decode_step_paged(params, toks, cache, pt, lens)
+
+    lowered = jax.jit(step).lower(
+        params, jax.ShapeDtypeStruct((slots, 1), i32, sharding=rep), cache,
+        jax.ShapeDtypeStruct((slots, 4), i32, sharding=rep),
+        jax.ShapeDtypeStruct((slots,), i32, sharding=rep))
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "%moe_glu" in text and "%moe_down" in text
+    assert "%_paged_decode" in text
+    assert "all-reduce" in text
+    rows = lowered.out_info[2]
+    assert rows.shape == (cfg.n_layers, 4, cfg.n_experts // 4)
+    assert rows.dtype == i32
