@@ -162,7 +162,11 @@ class ParamDef:
     shape: tuple[int, ...]
     logical_axes: tuple[Optional[str], ...]
     init: str = "normal"      # normal | zeros | ones | small_normal
-    dtype: Any = jnp.float32  # master dtype (cast to cfg.dtype in forward)
+    dtype: Any = jnp.float32  # master dtype
+    # the forward reads the leaf at float32 (norm scales and biases, the
+    # MoE router, the SSM dynamics); False: at cfg.dtype, which is the
+    # dtype the serving copy holds it in (Model.serving_params)
+    read_f32: bool = False
     # inputs summed into each output ("normal" init draws std 1/sqrt(fan_in));
     # None means shape[-2], right for (.., in, out) matrices.  Attention
     # projections (D, H, dh) / (H, dh, D) set it: their shape[-2] is a head

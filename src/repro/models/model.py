@@ -92,6 +92,30 @@ class Model:
         :func:`materialize`)."""
         return materialize(self.param_defs(), rng)
 
+    def serving_params(self, params):
+        """The weights as the serving steps read them: every leaf the
+        forward reads at ``cfg.dtype`` cast to it, the float32-read ones
+        (``ParamDef.read_f32``) passed through, in one jitted call that
+        keeps each leaf's sharding (an uncommitted leaf stays uncommitted,
+        free to follow a mesh's rules into the step as its master would).
+        The forward's own ``astype`` then casts nothing, so the steps
+        compute on the same values without casting the masters every step.
+        A tree with nothing to cast comes back as it is."""
+        dtype = self.cfg.dtype
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        defs = treedef.flatten_up_to(self.param_defs())
+        idx = [i for i, (d, x) in enumerate(zip(defs, leaves))
+               if not d.read_f32 and x.dtype != dtype]
+        if not idx:
+            return params
+        xs = [leaves[i] for i in idx]
+        cast = jax.jit(lambda xs: [x.astype(dtype) for x in xs],
+                       out_shardings=[x.sharding if x.committed else None
+                                      for x in xs])
+        for i, y in zip(idx, cast(xs)):
+            leaves[i] = y
+        return jax.tree_util.tree_unflatten(treedef, leaves)
+
     def param_structs(self):
         return shape_structs(self.param_defs())
 

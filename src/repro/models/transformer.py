@@ -46,11 +46,11 @@ from .common import ModelConfig, ParamDef
 def norm_defs(cfg: ModelConfig, d=None):
     d = d or cfg.d_model
     if cfg.norm_type == "rmsnorm":
-        return {"scale": ParamDef((d,), (None,), init="zeros")}
+        return {"scale": ParamDef((d,), (None,), init="zeros", read_f32=True)}
     if cfg.norm_type == "layernorm":
         return {
-            "scale": ParamDef((d,), (None,), init="ones"),
-            "bias": ParamDef((d,), (None,), init="zeros"),
+            "scale": ParamDef((d,), (None,), init="ones", read_f32=True),
+            "bias": ParamDef((d,), (None,), init="zeros", read_f32=True),
         }
     return {}  # nonparam_ln
 
@@ -68,8 +68,10 @@ def attn_defs(cfg: ModelConfig):
         defs["bk"] = ParamDef((Hkv, dh), ("kv", None), init="zeros")
         defs["bv"] = ParamDef((Hkv, dh), ("kv", None), init="zeros")
     if cfg.qk_norm:
-        defs["q_norm"] = {"scale": ParamDef((H * dh,), (None,), init="zeros")}
-        defs["k_norm"] = {"scale": ParamDef((Hkv * dh,), (None,), init="zeros")}
+        defs["q_norm"] = {"scale": ParamDef((H * dh,), (None,), init="zeros",
+                                            read_f32=True)}
+        defs["k_norm"] = {"scale": ParamDef((Hkv * dh,), (None,), init="zeros",
+                                            read_f32=True)}
     return defs
 
 
@@ -92,7 +94,8 @@ def mlp_defs(cfg: ModelConfig):
 def moe_defs(cfg: ModelConfig):
     D, E, Fe = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
     return {
-        "router": ParamDef((D, E), ("embed", None), init="small_normal"),
+        "router": ParamDef((D, E), ("embed", None), init="small_normal",
+                           read_f32=True),
         "w_gate": ParamDef((E, D, Fe), ("experts", "embed", "mlp")),
         "w_up": ParamDef((E, D, Fe), ("experts", "embed", "mlp")),
         "w_down": ParamDef((E, Fe, D), ("experts", "mlp", "embed")),
@@ -112,10 +115,11 @@ def ssm_defs(cfg: ModelConfig):
         "in_dt": ParamDef((D, n_heads), ("embed", "ssm_heads")),
         "conv_w": ParamDef((cfg.ssm_conv_dim, conv_ch), (None, None), init="small_normal"),
         "conv_b": ParamDef((conv_ch,), (None,), init="zeros"),
-        "A_log": ParamDef((n_heads,), ("ssm_heads",), init="zeros"),
-        "D": ParamDef((n_heads,), ("ssm_heads",), init="ones"),
-        "dt_bias": ParamDef((n_heads,), ("ssm_heads",), init="zeros"),
-        "norm_scale": ParamDef((d_inner,), ("ssm_inner",), init="zeros"),
+        "A_log": ParamDef((n_heads,), ("ssm_heads",), init="zeros", read_f32=True),
+        "D": ParamDef((n_heads,), ("ssm_heads",), init="ones", read_f32=True),
+        "dt_bias": ParamDef((n_heads,), ("ssm_heads",), init="zeros", read_f32=True),
+        "norm_scale": ParamDef((d_inner,), ("ssm_inner",), init="zeros",
+                               read_f32=True),
         "out_proj": ParamDef((d_inner, D), ("ssm_inner", "embed")),
     }
 

@@ -19,6 +19,10 @@ compiled programs instead of one per admission:
   ceil(2k/page) columns, and admission/eviction never triggers a
   recompile (it only rewrites one table row).
 
+The steps read a copy of the weights made once, in :meth:`__init__`, in
+the dtype the forward reads each leaf at (``Model.serving_params``): no
+step casts the float32 masters, which stay with the caller.
+
 Inactive slots are encoded entirely in data: an all-sentinel table row and
 ``kv_len == 0``.  Their decode lane appends into the sentinel page, reads
 back one garbage row, and produces logits the scheduler never samples —
@@ -94,6 +98,18 @@ def _next_pow2(n: int) -> int:
     return 1 << max(0, (n - 1)).bit_length()
 
 
+def _weight_counters(masters, served) -> dict:
+    """``serving.init``'s counters: ``weights_cast``, the leaves the serving
+    copy holds in another dtype than the caller's, and
+    ``step_weight_bytes``, the device bytes of the copy the steps read,
+    summed over the devices that hold its shards."""
+    pairs = list(zip(jax.tree_util.tree_leaves(masters),
+                     jax.tree_util.tree_leaves(served)))
+    return {"weights_cast": sum(m.dtype != s.dtype for m, s in pairs),
+            "step_weight_bytes": sum(
+                sh.data.nbytes for _, s in pairs for sh in s.addressable_shards)}
+
+
 class PagedServingEngine:
     def __init__(
         self,
@@ -115,15 +131,16 @@ class PagedServingEngine:
         if page_size & (page_size - 1):
             raise ValueError(f"page_size must be a power of two, got {page_size}")
         self.model = model
-        self.params = params
         self.page_size = page_size
         self.max_slots = max_slots
         self.max_cols = -(-max_context // page_size)
         if num_pages is None:
             # worst case: every slot at max_context, plus the sentinel
             num_pages = max_slots * self.max_cols + 1
-        with use_rules(rules), tracing.span("serving.init"):
+        with use_rules(rules), tracing.span("serving.init") as sp:
             self.cache = model.make_paged_cache(num_pages, page_size)
+            self.params = model.serving_params(params)
+            sp.attrs.update(_weight_counters(params, self.params))
         self.sched = ContinuousBatchingScheduler(
             max_slots, page_size, num_pages, policy=policy,
             max_preemptions=max_preemptions, faults=faults,

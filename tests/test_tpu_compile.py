@@ -150,6 +150,42 @@ def test_olmo_1b_paged_step_compiles(one_chip, mosaic, olmo_1b, step):
         assert "%_paged_decode" in text
 
 
+def _serving_structs(model, sharding_of):
+    """The serving copy's ShapeDtypeStructs (``Model.serving_params``):
+    float32 where the forward reads the leaf at float32, ``cfg.dtype``
+    elsewhere; ``sharding_of(def)`` places each leaf."""
+    from repro.models.common import ParamDef
+
+    return jax.tree_util.tree_map(
+        lambda d: jax.ShapeDtypeStruct(
+            d.shape, jnp.float32 if d.read_f32 else model.cfg.dtype,
+            sharding=sharding_of(d)),
+        model.param_defs(), is_leaf=lambda x: isinstance(x, ParamDef))
+
+
+@pytest.mark.parametrize("step", ["prefill_paged", "decode_step_paged"])
+def test_olmo_1b_paged_steps_on_serving_copy_hold_no_weight_copy(
+        one_chip, mosaic, olmo_1b, step):
+    """At the chat cell's shapes (32 slots, pages of 128, a 1024-token
+    bucket, 16 columns) the steps on the engine's bf16 copy need no
+    temporary copy of the weights.  On the f32 masters they held the 16
+    layers' bf16 cast, 2,148,354,560 temp bytes (decode) and 2,464,097,280
+    (prefill); on the copy 548,352 and 206,956,544."""
+    params = _serving_structs(olmo_1b, lambda d: one_chip)
+    cache = _tree_structs(
+        one_chip, jax.eval_shape(lambda: olmo_1b.make_paged_cache(128, 128)))
+    i32 = jnp.int32
+    if step == "prefill_paged":
+        args = (_struct(one_chip, (1, 1024), i32), cache,
+                _struct(one_chip, (1, 8), i32), _struct(one_chip, (1,), i32))
+    else:
+        args = (_struct(one_chip, (32, 1), i32), cache,
+                _struct(one_chip, (32, 16), i32),
+                _struct(one_chip, (32,), i32))
+    compiled = jax.jit(getattr(olmo_1b, step)).lower(params, *args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
 # --------------------------------------------------------------------------
 # training kernels: forward and fused backward
 
@@ -220,9 +256,12 @@ def test_fused_moe_glu_compiles_at_olmoe_prefill(one_chip, mosaic):
 
 def test_olmoe_paged_decode_compiles_on_model4_mesh(v5e_2x2, mosaic):
     """The paged decode step of the whole model (QK-norm on, dropless
-    top-8 of 64 experts, 16 per chip) compiles for four described chips:
-    the grouped kernels run per shard, the expert combine is an
-    all-reduce, and the step returns its rows per expert."""
+    top-8 of 64 experts, 16 per chip) compiles for four described chips
+    on the engine's bf16 copy of the weights: the grouped kernels run per
+    shard, the expert combine is an all-reduce, the step returns its rows
+    per expert, and it holds no temporary copy of the weights.  At these
+    shapes a chip's temp bytes read 3,424,521,216 on the f32 masters (the
+    bf16 cast of its share of the weights) and 135,636,992 on the copy."""
     from jax.sharding import NamedSharding, PartitionSpec
 
     from repro.distributed.sharding import make_rules, sanitize_spec, use_rules
@@ -244,7 +283,8 @@ def test_olmoe_paged_decode_compiles_on_model4_mesh(v5e_2x2, mosaic):
             structs, logical, is_leaf=lambda x: isinstance(x, tuple))
 
     slots, page_size, pages = 8, 128, 33
-    params = placed(model.param_structs(), model.param_logical())
+    params = _serving_structs(model, lambda d: NamedSharding(
+        mesh, sanitize_spec(mesh, rules.spec(*d.logical_axes), d.shape)))
     pool_defs = transformer.paged_cache_defs(cfg, pages, page_size)
     cache = placed(shape_structs(pool_defs), logical_specs(pool_defs))
     rep = NamedSharding(mesh, PartitionSpec())
@@ -258,11 +298,13 @@ def test_olmoe_paged_decode_compiles_on_model4_mesh(v5e_2x2, mosaic):
         params, jax.ShapeDtypeStruct((slots, 1), i32, sharding=rep), cache,
         jax.ShapeDtypeStruct((slots, 4), i32, sharding=rep),
         jax.ShapeDtypeStruct((slots,), i32, sharding=rep))
-    text = lowered.compile().as_text()
+    compiled = lowered.compile()
+    text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "%moe_glu" in text and "%moe_down" in text
     assert "%_paged_decode" in text
     assert "all-reduce" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
     rows = lowered.out_info[2]
     assert rows.shape == (cfg.n_layers, 4, cfg.n_experts // 4)
     assert rows.dtype == i32
